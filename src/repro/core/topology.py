@@ -1,7 +1,9 @@
 """Hardware constants for roofline analysis and the paper's cost model.
 
 Two parameter sets coexist:
-  * ``TPU_V5E``  — the executable-reproduction target (roofline terms).
+  * ``TPU_V5E``  — the executable-reproduction target (roofline terms),
+    reachable by the ``device_kind`` JAX reports through
+    :func:`chip_for_kind`.
   * ``PAPER_28NM`` — the paper's 28nm CMOS evaluation context, used by
     ``core.cost_model`` to reproduce the paper's figures.
 """
@@ -38,6 +40,26 @@ TPU_V5E = ChipSpec(
     vmem_bytes=64 * 1024**2,
     clock_hz=0.94e9,
 )
+
+# TPU chips by the ``device_kind`` string JAX reports for them.  Peaks of
+# TPU_V5E: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s).
+TPU_CHIPS: dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_kind(device_kind: str) -> ChipSpec:
+    """Peak table entry for a TPU ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return TPU_CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for TPU device kind {device_kind!r}; "
+            f"known: {sorted(TPU_CHIPS)} (add it to TPU_CHIPS with its "
+            f"source)") from None
+
 
 # VPU throughput estimate used by the decompression napkin math in DESIGN.md:
 # 8 sublanes x 128 lanes x ~2 ALU ops / cycle.

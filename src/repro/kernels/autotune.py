@@ -44,6 +44,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.plan import QVALUE_BITS
+from repro.core.topology import chip_for_kind
 from repro.kernels import registry
 from repro.kernels.registry import KernelImpl, ProblemKey
 
@@ -62,10 +63,11 @@ __all__ = [
 
 CACHE_VERSION = 1
 
-# crude per-backend throughput constants for the prior (the prior only needs
-# to *order* candidates; measurement fixes the magnitudes)
-_PEAK_FLOPS = {"cpu": 5e10, "gpu": 1e13, "tpu": 2e14, "interpret": 5e10}
-_MEM_BW = {"cpu": 2e10, "gpu": 1e12, "tpu": 1.2e12, "interpret": 2e10}
+# crude throughput constants for the prior off the TPU (the prior only
+# needs to *order* candidates; measurement fixes the magnitudes).  TPU keys
+# take the chip's published peaks from core.topology instead.
+_PEAK_FLOPS = {"cpu": 5e10, "gpu": 1e13, "interpret": 5e10}
+_MEM_BW = {"cpu": 2e10, "gpu": 1e12, "interpret": 2e10}
 # the Pallas interpreter executes the kernel body in Python per grid step —
 # orders of magnitude slower than compiled jnp; the prior must know that so
 # a cold cache on CPU never routes the hot path through the interpreter.
@@ -78,6 +80,25 @@ def default_cache_path() -> pathlib.Path:
     if env:
         return pathlib.Path(env).expanduser()
     return pathlib.Path("~/.cache/repro/tuning_cache.json").expanduser()
+
+
+def tpu_device_kind() -> str:
+    """``device_kind`` of the TPU this process drives (ranking a TPU key
+    needs the chip's peaks, and there is no default chip)."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"ranking a TPU problem key needs a TPU device; the default "
+            f"device is {dev.platform!r}")
+    return dev.device_kind
+
+
+def _peaks(backend: str) -> tuple[float, float]:
+    """(peak FLOP/s, memory bytes/s) the prior assumes for a backend."""
+    if backend == "tpu":
+        chip = chip_for_kind(tpu_device_kind())
+        return chip.peak_bf16_flops, chip.hbm_bandwidth
+    return _PEAK_FLOPS.get(backend, 5e10), _MEM_BW.get(backend, 2e10)
 
 
 def key_str(key: ProblemKey) -> str:
@@ -219,8 +240,7 @@ def predict_us(key: ProblemKey, impl: KernelImpl, params: dict) -> float:
     dense_w_bytes = k * n * itemsize
 
     backend = key.backend
-    peak = _PEAK_FLOPS.get(backend, 5e10)
-    bw = _MEM_BW.get(backend, 2e10)
+    peak, bw = _peaks(backend)
 
     if impl.name == "jnp_oracle":
         # scatter-decompress materializes the dense matrix, then a dense dot

@@ -3,7 +3,8 @@
 The TPU-native adaptation of the paper's insight (DESIGN.md §2): the natural
 decompression granule on a TPU is the (8, 128) vector register, not a single
 element.  Decompression of a (bk, bn) macro tile is then a short loop of
-whole-register dynamic-slice copies — near line rate on the VPU — and macro
+whole-register adds into a VMEM tile at each block's row offset — near line
+rate on the VPU — and macro
 tiles whose ``tile_nnz == 0`` skip their MXU dot entirely (a *compute* win
 the paper's always-dense array cannot realize; the paper's structured-sparsity
 "bypass" mode, Section V-A, taken one step further).
@@ -20,8 +21,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.core.formats import BlockCSR
+from repro.kernels.sod_matmul import (
+    _dequant_codes,
+    quant_side_inputs,
+    resolve_interpret,
+)
 
 __all__ = ["block_matmul_pallas"]
 
@@ -31,14 +36,15 @@ def _block_matmul_kernel(
     ids_ref,     # SMEM (Kt, Nt, bcap) int32, -1 = padding
     x_ref,       # (bm, bk)
     bvals_ref,   # (1, 1, bcap, br, bn)
-    *refs,       # [scale_ref (1,1) | cb_ref (1,ncodes)], o_ref, slab_ref, acc_ref
+    *refs,       # [q_ref: SMEM scale (Kt, Nt) | SMEM codebook (1, ncodes)],
+                 # o_ref, slab_ref, acc_ref, tile_ref
     kt_total: int,
     bk: int,
     br: int,
     bcap: int,
     qmode: str = "none",
 ):
-    o_ref, slab_ref, acc_ref = refs[-3:]
+    o_ref, slab_ref, acc_ref, tile_ref = refs[-4:]
     q_ref = refs[0] if qmode != "none" else None
     n = pl.program_id(0)
     m = pl.program_id(1)
@@ -47,35 +53,29 @@ def _block_matmul_kernel(
 
     @pl.when(jnp.logical_and(m == 0, nnz > 0))
     def _decompress():
-        bn_ = bvals_ref.shape[-1]
-        cb = q_ref[...] if qmode == "codebook" else None
-        # Quantized blocks accumulate in f32 (codes dequantize per block;
-        # the shared per-tile scale multiplies the finished tile once).
-        tile_dtype = bvals_ref.dtype if qmode == "none" else jnp.float32
+        # Blocks accumulate in an f32 VMEM tile (quantized codes dequantize
+        # per block; the shared per-tile scale multiplies the finished tile
+        # once).
+        tile_ref[...] = jnp.zeros_like(tile_ref)
 
-        def body(s, tile):
+        def body(s, carry):
             bid = ids_ref[k, n, s]
             # Padding (bid == -1) contributes zeros added at offset 0 — a
             # no-op because real block ids are unique and values are 0
             # (codebook entry 0 is pinned to 0.0 for the same reason).
-            off = jnp.maximum(bid, 0) * br
+            off = pl.multiple_of(jnp.maximum(bid, 0) * br, br)
             blk = bvals_ref[0, 0, s]
             if qmode == "codebook":
-                idx = blk.astype(jnp.int32)
-                deq = jnp.zeros(blk.shape, jnp.float32)
-                for code in range(cb.shape[-1]):
-                    deq += jnp.where(idx == code, cb[0, code], 0.0)
-                blk = deq
-            elif qmode != "none":
+                blk = _dequant_codes(blk.astype(jnp.int32), q_ref)
+            else:
                 blk = blk.astype(jnp.float32)
-            cur = jax.lax.dynamic_slice(tile, (off, 0), (br, tile.shape[1]))
-            return jax.lax.dynamic_update_slice(tile, cur + blk, (off, 0))
+            tile_ref[pl.ds(off, br), :] += blk
+            return carry
 
-        tile = jax.lax.fori_loop(
-            0, bcap, body, jnp.zeros((bk, bn_), tile_dtype)
-        )
+        jax.lax.fori_loop(0, bcap, body, 0)
+        tile = tile_ref[...]
         if qmode in ("int8", "fp8"):
-            tile = tile * q_ref[0, 0]
+            tile = tile * q_ref[k, n]
         slab_ref[k] = tile.astype(slab_ref.dtype)
 
     @pl.when(k == 0)
@@ -101,10 +101,13 @@ def block_matmul_pallas(
     packed: BlockCSR,
     *,
     bm: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
 ):
-    """``x @ decompress(packed)`` with zero-macro-tile skip, 2-D ``x``."""
+    """``x @ decompress(packed)`` with zero-macro-tile skip, 2-D ``x``.
+
+    ``interpret=None`` compiles for a TPU backend and interprets elsewhere.
+    """
     out_dtype = out_dtype or x.dtype
     kt, nt = packed.grid
     bk, bn = packed.tile
@@ -133,15 +136,7 @@ def block_matmul_pallas(
     )
 
     qmode = packed.qmode
-    extra_in = []
-    extra_specs = []
-    if qmode in ("int8", "fp8"):
-        extra_in.append(packed.scale)
-        extra_specs.append(pl.BlockSpec((1, 1), lambda n, m, k, *_: (k, n)))
-    elif qmode == "codebook":
-        cb = packed.codebook.reshape(1, -1)
-        extra_in.append(cb)
-        extra_specs.append(pl.BlockSpec(cb.shape, lambda n, m, k, *_: (0, 0)))
+    extra_in, extra_specs = quant_side_inputs(packed)
 
     kernel = functools.partial(
         _block_matmul_kernel, kt_total=kt, bk=bk, br=br, bcap=bcap,
@@ -161,15 +156,16 @@ def block_matmul_pallas(
         scratch_shapes=[
             pltpu.VMEM((kt, bk, bn), x.dtype),
             pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((bk, bn), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_dim, nt * bn), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         cost_estimate=cost,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed.tile_nnz, packed.block_ids, x, packed.block_vals, *extra_in)

@@ -15,9 +15,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.core.formats import TiledCSC
-from repro.kernels.sod_matmul import _decompress_tile
+from repro.kernels.sod_matmul import (
+    _decompress_tile,
+    quant_side_inputs,
+    resolve_interpret,
+)
 
 __all__ = ["decompress_pallas"]
 
@@ -26,12 +29,10 @@ def _decompress_kernel(vals_ref, rows_ref, *refs, bk, slot_chunk, qmode):
     """One (bk, bn) tile per grid step; dequant fused as in the matmul."""
     o_ref = refs[-1]
     q_ref = refs[0] if qmode != "none" else None
-    vals = vals_ref[0, 0]
-    rows = rows_ref[0, 0].astype(jnp.int32)
-    cb = q_ref[...] if qmode == "codebook" else None
-    tile = _decompress_tile(vals, rows, bk, slot_chunk, codebook=cb)
+    tile = _decompress_tile(vals_ref, rows_ref, bk, slot_chunk,
+                            cb_ref=q_ref if qmode == "codebook" else None)
     if qmode in ("int8", "fp8"):
-        tile = tile * q_ref[0, 0]
+        tile = tile * q_ref[pl.program_id(0), pl.program_id(1)]
     o_ref[...] = tile.astype(o_ref.dtype)
 
 
@@ -40,13 +41,14 @@ def decompress_pallas(
     packed: TiledCSC,
     *,
     slot_chunk: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
 ):
     """Dense (Kp, Np) matrix from a TiledCSC operand (padded shape).
 
     Quantized operands dequantize in-kernel; their default output dtype is
     float32 (the stored value dtype is the code, not a value).
+    ``interpret=None`` compiles for a TPU backend and interprets elsewhere.
     """
     qmode = packed.qmode
     out_dtype = out_dtype or (
@@ -66,15 +68,7 @@ def decompress_pallas(
         ),
         transcendentals=0,
     )
-    extra_in = []
-    extra_specs = []
-    if qmode in ("int8", "fp8"):
-        extra_in.append(packed.scale)
-        extra_specs.append(pl.BlockSpec((1, 1), lambda k, n: (k, n)))
-    elif qmode == "codebook":
-        cb = packed.codebook.reshape(1, -1)
-        extra_in.append(cb)
-        extra_specs.append(pl.BlockSpec(cb.shape, lambda k, n: (0, 0)))
+    extra_in, extra_specs = quant_side_inputs(packed)
     kernel = functools.partial(_decompress_kernel, bk=bk,
                                slot_chunk=slot_chunk, qmode=qmode)
     out = pl.pallas_call(
@@ -87,10 +81,10 @@ def decompress_pallas(
         ],
         out_specs=pl.BlockSpec((bk, bn), lambda k, n: (k, n)),
         out_shape=jax.ShapeDtypeStruct((kt * bk, nt * bn), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=cost,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed.vals, packed.rows, *extra_in)
     return out

@@ -9,8 +9,8 @@ autotuner's persisted winners (:mod:`repro.kernels.autotune`):
 
 * ``impl="auto"``   — registry dispatch: tuned entry if the tuning cache has
   one for this (format, shape, density, backend, mesh), else the cost-model-
-  prior default.  On CPU this is the differentiable jnp oracle; on TPU (or
-  under ``backend="interpret"``) the fused Pallas kernel.
+  prior default.  On CPU this is the differentiable jnp oracle; on one TPU
+  (or under ``backend="interpret"``) the fused Pallas kernel.
 * ``impl="pallas"`` — force the Pallas kernel (interpret mode off-TPU).
 * ``impl="jnp"``    — force the jnp scatter oracle.
 
@@ -170,8 +170,12 @@ def sod_matmul(
     return y.reshape(*lead, n_logical)
 
 
-def decompress(w, *, impl: str = "auto", interpret: bool = True) -> jax.Array:
-    """Dense matrix from a packed operand (logical, un-padded shape)."""
+def decompress(w, *, impl: str = "auto",
+               interpret: bool | None = None) -> jax.Array:
+    """Dense matrix from a packed operand (logical, un-padded shape).
+
+    ``interpret=None`` compiles the Pallas kernel on a TPU backend and
+    interprets it elsewhere."""
     if isinstance(w, TiledCSC) and impl in ("auto", "pallas"):
         dense = decompress_pallas(w, interpret=interpret)
         return dense[: w.shape[0], : w.shape[1]]

@@ -50,6 +50,7 @@ __all__ = [
     "static_density",
     "current_backend",
     "set_backend_override",
+    "unwrapped_may_be_sharded",
     "kernel_hash",
     "record_dispatches",
     "note_dispatch",
@@ -110,8 +111,8 @@ class KernelImpl:
     # :mod:`repro.runtime.spmd` (``mesh_axes`` names the axis roles the
     # wrapper supports).  pallas_call still has no GSPMD partitioning rule,
     # so a Pallas impl traced *directly* under pjit (dispatch with an empty
-    # ``ProblemKey.mesh``) remains off-limits on a cold TPU cache — see
-    # choose().
+    # ``ProblemKey.mesh`` in a multi-device process) remains off-limits on
+    # a cold TPU cache — see unwrapped_may_be_sharded().
     spmd_partitionable: bool
     priority: int            # tie-break when the prior can't separate
     param_space: Callable[[ProblemKey], dict[str, tuple]]
@@ -277,14 +278,32 @@ def problem_key(w, m: int, backend: str | None = None,
     )
 
 
+def unwrapped_may_be_sharded(key: ProblemKey) -> bool:
+    """Whether a TPU dispatch could sit inside a GSPMD-sharded step.
+
+    ``pallas_call`` has no GSPMD partitioning rule, so a Pallas kernel traced
+    directly into a pjit step whose weights are sharded across chips would
+    not partition.  Inside the :mod:`repro.runtime.spmd` shard_map wrapper
+    the key carries a mesh signature and each device traces its own shard,
+    so that is safe; so is a process that sees one device, where nothing can
+    be sharded.  What remains is an unwrapped TPU dispatch in a process that
+    sees several devices.
+    """
+    return key.backend == "tpu" and not key.mesh and jax.device_count() > 1
+
+
 def choose(key: ProblemKey, tuned: dict | None = None
            ) -> tuple[KernelImpl, dict]:
     """Resolve (impl, params) for a problem.
 
     ``tuned`` is an autotune cache entry ``{"impl": ..., "params": ...}``;
     when absent (cold cache inside a trace — we never measure there) the
-    highest-priority capable impl runs with its defaults, which the
-    cost-model prior in :mod:`autotune` later refines.
+    impl and params the cost-model prior in :mod:`autotune` ranks cheapest
+    run, restricted to natively partitionable impls where
+    :func:`unwrapped_may_be_sharded` says a Pallas kernel could be traced
+    under GSPMD.  Explicitly tuned entries may still promote the Pallas
+    kernels there (tuning runs per-host, outside pjit, so the operator
+    opted in knowingly).
     """
     if tuned is not None:
         impl = _REGISTRY.get(tuned.get("impl", ""))
@@ -294,20 +313,11 @@ def choose(key: ProblemKey, tuned: dict | None = None
             note_dispatch(key, impl, params, "tuned")
             return impl, params
     # cold cache: cheapest candidate under the analytical prior (deferred
-    # import — autotune imports this module at top level).  On a real TPU
-    # the model step typically runs under pjit with sharded weights, and
-    # pallas_call cannot be GSPMD-partitioned — so an *untuned* TPU
-    # dispatch with no mesh signature (i.e. NOT inside the
-    # repro.runtime.spmd shard_map wrapper, where pallas is per-device and
-    # therefore legal) is restricted to natively partitionable impls (the
-    # XLA scatter+dot oracle, which is what the pre-registry code always
-    # ran).  Explicitly tuned entries may still promote the pallas kernels
-    # (tuning runs per-host, outside pjit, so the operator opted in
-    # knowingly).
+    # import — autotune imports this module at top level).
     from repro.kernels import autotune
 
     ranked = autotune.rank_candidates(key)
-    if key.backend == "tpu" and not key.mesh:
+    if unwrapped_may_be_sharded(key):
         safe = [t for t in ranked
                 if t[1].spmd_partitionable and not t[1].requires_shard_map]
         ranked = safe or ranked
@@ -414,6 +424,14 @@ def kernel_hash() -> str:
 # built-in implementations
 # ---------------------------------------------------------------------------
 def _sanitize_slot_chunk(cap: int, slot_chunk: int) -> int:
+    """Largest slot chunk at most the request (at least 8) that divides
+    ``cap`` and is a multiple of 8: the kernel loads each chunk of slots at
+    offset ``c * slot_chunk``, and Mosaic accepts the load only where it can
+    prove that offset sublane (8) aligned.  A ``cap`` that is not a
+    multiple of 8 (interpreter only) takes its largest divisor."""
+    for c in range(min(max(slot_chunk, 8), cap) // 8 * 8, 0, -8):
+        if cap % c == 0:
+            return c
     slot_chunk = max(min(slot_chunk, cap), 1)
     while cap % slot_chunk:
         slot_chunk -= 1
@@ -424,7 +442,13 @@ def _dtype_name(out_dtype) -> str | None:
     return jnp.dtype(out_dtype).name if out_dtype is not None else None
 
 
-def _run_pallas_fused(x2, w, *, out_dtype=None, backend="interpret",
+def _interpret(backend: str | None) -> bool:
+    """Pallas kernels compile for the chip on the ``tpu`` backend and run
+    in the interpreter on every other (``None`` = the current backend)."""
+    return (backend or current_backend()) != "tpu"
+
+
+def _run_pallas_fused(x2, w, *, out_dtype=None, backend=None,
                       bm=128, slot_chunk=8, k_slab=0):
     from repro.kernels import vjp
 
@@ -432,17 +456,18 @@ def _run_pallas_fused(x2, w, *, out_dtype=None, backend="interpret",
         vjp.pick_bm(x2.shape[0], bm),
         _sanitize_slot_chunk(w.cap, slot_chunk),
         k_slab,
-        backend != "tpu",
+        _interpret(backend),
         _dtype_name(out_dtype),
     )
     return fn(x2, w)
 
 
-def _run_pallas_block(x2, w, *, out_dtype=None, backend="interpret", bm=128):
+def _run_pallas_block(x2, w, *, out_dtype=None, backend=None, bm=128):
     from repro.kernels import vjp
 
     fn = vjp.block_matmul(
-        vjp.pick_bm(x2.shape[0], bm), backend != "tpu", _dtype_name(out_dtype)
+        vjp.pick_bm(x2.shape[0], bm), _interpret(backend),
+        _dtype_name(out_dtype)
     )
     return fn(x2, w)
 
@@ -463,12 +488,12 @@ def _jitted_ref(name: str) -> Callable:
     return _JITTED[name]
 
 
-def _run_jnp_oracle(x2, w, *, out_dtype=None, backend="cpu"):
+def _run_jnp_oracle(x2, w, *, out_dtype=None, backend=None):
     fn = _jitted_ref("tiled" if isinstance(w, TiledCSC) else "block")
     return fn(x2, w, out_dtype=out_dtype)
 
 
-def _run_dense(x2, w, *, out_dtype=None, backend="cpu"):
+def _run_dense(x2, w, *, out_dtype=None, backend=None):
     return _jitted_ref("dense")(x2, w, out_dtype=out_dtype)
 
 
@@ -526,8 +551,8 @@ def _block_canonical(key: ProblemKey, params: dict, m: int) -> dict:
 # zero-tile fractions).
 # mesh-legal via the repro.runtime.spmd shard_map wrappers ("data" =
 # M-sharding / compressed FSDP gather, "model" = column/row tensor
-# parallelism); dispatch outside the wrapper (empty key.mesh) still treats
-# them as unpartitionable — see choose().
+# parallelism); dispatch outside the wrapper in a multi-device process still
+# treats them as unpartitionable — see unwrapped_may_be_sharded().
 register(KernelImpl(
     name="pallas_fused",
     formats=("tiled_csc",),

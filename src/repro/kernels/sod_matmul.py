@@ -25,50 +25,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 from repro.core.formats import TiledCSC
 
-__all__ = ["sod_matmul_pallas"]
+__all__ = ["sod_matmul_pallas", "resolve_interpret"]
 
 
-def _dequant_chunk(v: jax.Array, codebook: jax.Array) -> jax.Array:
-    """Codebook dequant of one slot chunk: unrolled compare-select over the
-    (small, static) shared-value table — same VPU idiom as the row-index
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means: compile for the chip when JAX's default
+    backend is a TPU, run the Pallas interpreter anywhere else."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def _dequant_codes(idx: jax.Array, cb_ref) -> jax.Array:
+    """Codebook dequant: unrolled compare-select over the (small, static)
+    shared-value table held in SMEM — same VPU idiom as the row-index
     compare-accumulate, no gather needed."""
-    idx = v.astype(jnp.int32)
-    out = jnp.zeros(v.shape, jnp.float32)
-    for code in range(codebook.shape[-1]):
-        out += jnp.where(idx == code, codebook[0, code], 0.0)
+    out = jnp.zeros(idx.shape, jnp.float32)
+    for code in range(cb_ref.shape[-1]):
+        out += jnp.where(idx == code, cb_ref[0, code], 0.0)
     return out
 
 
 def _decompress_tile(
-    vals: jax.Array,  # (cap, bn)
-    rows: jax.Array,  # (cap, bn) int32, -1 = padding
+    vals_ref,  # (1, 1, cap, bn) block of stored values / codes
+    rows_ref,  # (1, 1, cap, bn) block of row indices, -1 = padding
     bk: int,
     slot_chunk: int,
-    codebook: jax.Array | None = None,  # (1, ncodes) for qmode='codebook'
+    cb_ref=None,  # SMEM (1, ncodes) for qmode='codebook'
 ) -> jax.Array:
     """Compare-accumulate decompression of one (bk, bn) tile (VPU loop).
 
-    Accumulates in float32 — for quantized operands ``vals`` holds the raw
-    codes; codebook indices dequantize per chunk here, while int8/fp8 codes
-    sum raw and the caller applies the per-tile scale once to the finished
-    tile (``Σ qᵢ·s = s·Σ qᵢ``), keeping dequant off the inner loop.
+    Each step reads one ``slot_chunk`` of slots straight from the refs and
+    adds every slot's value into the tile row it names.  Accumulates in
+    float32 — for quantized operands the values are raw codes; codebook
+    indices dequantize per chunk here, while int8/fp8 codes sum raw and the
+    caller applies the per-tile scale once to the finished tile
+    (``Σ qᵢ·s = s·Σ qᵢ``), keeping dequant off the inner loop.
     """
-    cap, bn = vals.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bk, 1, bn), 0)
+    cap, bn = vals_ref.shape[-2:]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0)
 
     def body(c, acc):
-        r = jax.lax.dynamic_slice(rows, (c * slot_chunk, 0), (slot_chunk, bn))
-        v = jax.lax.dynamic_slice(vals, (c * slot_chunk, 0), (slot_chunk, bn))
-        if codebook is None:
+        start = pl.multiple_of(c * slot_chunk, slot_chunk)
+        r = rows_ref[0, 0, pl.ds(start, slot_chunk), :].astype(jnp.int32)
+        v = vals_ref[0, 0, pl.ds(start, slot_chunk), :]
+        if cb_ref is None:
             vf = v.astype(jnp.float32)
         else:
-            vf = _dequant_chunk(v, codebook)
-        hit = iota == r[None, :, :]
-        contrib = jnp.where(hit, vf[None, :, :], 0.0)
-        return acc + jnp.sum(contrib, axis=1)
+            vf = _dequant_codes(v.astype(jnp.int32), cb_ref)
+        for j in range(slot_chunk):
+            acc += jnp.where(iota == r[j:j + 1, :], vf[j:j + 1, :], 0.0)
+        return acc
 
     n_chunks = cap // slot_chunk
     tile = jax.lax.fori_loop(
@@ -81,7 +90,8 @@ def _sod_matmul_kernel(
     x_ref,      # (bm, bk)
     vals_ref,   # (1, 1, cap, bn)
     rows_ref,   # (1, 1, cap, bn)
-    *refs,      # [scale_ref (1,1) | cb_ref (1,ncodes)], o_ref, slab_ref, acc_ref
+    *refs,      # [q_ref: SMEM scale (Kt, Nt) | SMEM codebook (1, ncodes)],
+                # o_ref, slab_ref, acc_ref
     kt_total: int,
     bk: int,
     slot_chunk: int,
@@ -90,6 +100,7 @@ def _sod_matmul_kernel(
 ):
     o_ref, slab_ref, acc_ref = refs[-3:]
     q_ref = refs[0] if qmode != "none" else None
+    n = pl.program_id(0)
     m = pl.program_id(1)
     k = pl.program_id(2)
     resident = slab_len >= kt_total
@@ -102,12 +113,11 @@ def _sod_matmul_kernel(
     # Dequantization fuses here too — the scale rides the same residency,
     # so quantized operands cost zero extra HBM round trips.
     def _decompress():
-        vals = vals_ref[0, 0]
-        rows = rows_ref[0, 0].astype(jnp.int32)
-        cb = q_ref[...] if qmode == "codebook" else None
-        tile = _decompress_tile(vals, rows, bk, slot_chunk, codebook=cb)
+        cb_ref = q_ref if qmode == "codebook" else None
+        tile = _decompress_tile(vals_ref, rows_ref, bk, slot_chunk,
+                                cb_ref=cb_ref)
         if qmode in ("int8", "fp8"):
-            tile = tile * q_ref[0, 0]
+            tile = tile * q_ref[k, n]
         slab_ref[slot] = tile.astype(slab_ref.dtype)
 
     if resident:
@@ -128,6 +138,20 @@ def _sod_matmul_kernel(
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def quant_side_inputs(packed) -> tuple[list, list]:
+    """Extra (inputs, specs) a quantized operand appends to a kernel call.
+
+    The per-tile scale (int8/fp8) and the shared-value codebook both ride
+    whole in SMEM: the kernel reads the scale of tile ``(k, n)`` as a
+    scalar, and the codebook's entries as compare-select constants."""
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if packed.qmode in ("int8", "fp8"):
+        return [packed.scale.astype(jnp.float32)], [smem]
+    if packed.qmode == "codebook":
+        return [packed.codebook.reshape(1, -1).astype(jnp.float32)], [smem]
+    return [], []
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bm", "slot_chunk", "k_slab", "interpret", "out_dtype"),
@@ -139,7 +163,7 @@ def sod_matmul_pallas(
     bm: int = 128,
     slot_chunk: int = 8,
     k_slab: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
 ):
     """``x @ decompress(packed)`` fused, for 2-D ``x`` of shape (M, Kp).
@@ -152,6 +176,7 @@ def sod_matmul_pallas(
     0 (default) keeps all ``Kt`` tiles resident and decompresses each once;
     ``0 < k_slab < Kt`` keeps only ``k_slab`` tiles and re-decompresses per
     M-block — the autotuner's knob for weights whose full slab exceeds VMEM.
+    ``interpret=None`` compiles for a TPU backend and interprets elsewhere.
     """
     out_dtype = out_dtype or x.dtype
     kt, nt = packed.grid
@@ -181,21 +206,8 @@ def sod_matmul_pallas(
         transcendentals=0,
     )
 
-    # Quantized operands append one extra input: the (Kt, Nt) per-tile
-    # scale (tile-indexed alongside vals) or the shared-value codebook
-    # (same (1, ncodes) block at every grid step).
     qmode = packed.qmode
-    extra_in = []
-    extra_specs = []
-    if qmode in ("int8", "fp8"):
-        extra_in.append(packed.scale)
-        extra_specs.append(pl.BlockSpec((1, 1), lambda n, m, k: (k, n)))
-    elif qmode == "codebook":
-        cb = packed.codebook.reshape(1, -1)
-        extra_in.append(cb)
-        extra_specs.append(
-            pl.BlockSpec(cb.shape, lambda n, m, k: (0, 0)))
-
+    extra_in, extra_specs = quant_side_inputs(packed)
     kernel = functools.partial(
         _sod_matmul_kernel, kt_total=kt, bk=bk, slot_chunk=slot_chunk,
         slab_len=slab_len, qmode=qmode,
@@ -215,9 +227,9 @@ def sod_matmul_pallas(
             pltpu.VMEM((slab_len, bk, bn), x.dtype),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         cost_estimate=cost,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, packed.vals, packed.rows, *extra_in)

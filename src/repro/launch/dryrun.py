@@ -1,9 +1,8 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ruff: noqa: E402  (the env var MUST precede any jax-importing module)
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
+
+A host-device analysis tool: ``main`` pins this process and the children it
+starts to the CPU platform with 512 virtual devices, so on a chip host it
+never contends for the chip (one process per chip).
 
 For each cell this script:
   1. builds the production mesh (16×16 single-pod / 2×16×16 multi-pod),
@@ -23,6 +22,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import pathlib
 import re
 import subprocess
@@ -322,7 +322,23 @@ def _result_path(arch, shape, multi_pod, sod_mode) -> pathlib.Path:
     return RESULTS_DIR / f"{arch}__{shape}__{mesh}__{sod_mode or 'dense'}.json"
 
 
+HOST_DEVICES = 512
+
+
+def _pin_to_host() -> None:
+    """Run on the CPU platform with ``HOST_DEVICES`` virtual devices, here
+    and in every child (they inherit the environment).  Must run before
+    anything in the process touches a JAX device."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform_device_count")]
+    flags.append(f"--xla_force_host_platform_device_count={HOST_DEVICES}")
+    os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main():
+    _pin_to_host()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=configs.ARCH_NAMES)
     ap.add_argument("--shape", choices=tuple(SHAPES))

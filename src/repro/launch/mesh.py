@@ -1,8 +1,8 @@
 """Production meshes.
 
 ``make_production_mesh`` is a FUNCTION (importing this module never touches
-jax device state).  The dry-run forces 512 host platform devices before any
-jax import; everything else sees the real device count.
+jax device state).  The dry-run pins itself to 512 host platform devices
+before it touches one; everything else sees the real device count.
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            "before importing jax (launch/dryrun.py does this)."
+            "before jax creates its backend (launch/dryrun.py does this)."
         )
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 

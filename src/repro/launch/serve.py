@@ -11,9 +11,11 @@ Example:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import pathlib
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.core.sod import SoDConfig, sodify_params
 from repro.data.pipeline import SyntheticLMData
 from repro.kernels import registry as kreg
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 
 
@@ -117,10 +120,23 @@ def engine_main(args, model, params, plan, draft_params=None,
     return summary
 
 
-def main(argv=None):
-    """CLI entry point: static batched serving or the continuous-batching
-    engine (``--engine``), with optional Sparse-on-Dense packing and
-    speculative decoding.  Prints and returns a JSON summary."""
+@dataclasses.dataclass
+class Served:
+    """What :func:`build` makes from the command line: the model, its
+    (optionally SoD-packed) params and pack plan, and the draft tier."""
+
+    cfg: Any
+    model: LM
+    params: Any
+    plan: Any = None
+    draft_params: Any = None
+    draft_plan: Any = None
+    tune_stats: dict | None = None
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and cross-check the serving flags (exits on a bad combination,
+    as argparse does)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b",
                     choices=configs.ARCH_NAMES)
@@ -212,13 +228,6 @@ def main(argv=None):
                     help="write a counters/gauges/histograms metrics "
                          "snapshot to PATH")
     args = ap.parse_args(argv)
-
-    tracer = None
-    if args.trace:
-        # install before any instrumented object exists: the engine,
-        # scheduler, and kernel registry capture the global tracer
-        tracer = obs.install_tracer(obs.Tracer())
-
     if args.prefix_sharing and not args.prefill_chunk:
         ap.error("--prefix-sharing requires --prefill-chunk (prefill must "
                  "be able to start mid-prompt to skip shared positions)")
@@ -231,26 +240,32 @@ def main(argv=None):
                  "run against the paged KV cache)")
     if args.draft_sparsity is not None and not args.spec_decode:
         ap.error("--draft-sparsity requires --spec-decode")
-    cfg = configs.get_config(args.arch)
-    if args.reduced:
-        cfg = configs.reduced(cfg)
     if args.quantize != "none" and not args.sod:
         ap.error("--quantize requires Sparse-on-Dense packing "
                  "(pass --sod tiled_csc|block_csr)")
     if args.quantize == "auto" and args.plan != "auto":
         ap.error("--quantize auto needs the planner (pass --plan auto)")
+    if args.plan and not args.sod:
+        ap.error("--plan requires Sparse-on-Dense packing "
+                 "(pass --sod tiled_csc|block_csr)")
+    return args
+
+
+def build(args: argparse.Namespace) -> Served:
+    """Model, random params from ``--seed``, and (with ``--sod``) the pack
+    plan and packed params, tuned first when ``--autotune`` asks."""
+    cfg = configs.get_config(args.arch)
+    if args.reduced:
+        cfg = configs.reduced(cfg)
     if args.sod:
         cfg = cfg.with_(sod=SoDConfig(
             mode=args.sod, density=args.density, min_dim=64,
             qmode=args.quantize if args.quantize != "auto" else "none"))
     model = LM(cfg)
-    key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
+    # one compiled program instead of an eager dispatch per init op
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     tune_stats = None
     plan = None
-    if args.plan and not cfg.sod.enabled:
-        ap.error("--plan requires Sparse-on-Dense packing "
-                 "(pass --sod tiled_csc|block_csr)")
     # prefill consumes (batch·prompt_len, K); decode (batch, K).  Engine
     # mode decodes max_slots rows and prefills one prompt at a time, at
     # the page-aligned bucket length for attention families (batch-1
@@ -301,12 +316,31 @@ def main(argv=None):
             print(f"autotune: {tune_stats} -> {cache.path}")
     if args.plan_json and plan is not None:
         print(f"pack plan -> {plan.save(args.plan_json)}")
+    return Served(cfg, model, params, plan, draft_params, draft_plan,
+                  tune_stats)
+
+
+def main(argv=None):
+    """CLI entry point: static batched serving or the continuous-batching
+    engine (``--engine``), with optional Sparse-on-Dense packing and
+    speculative decoding.  Prints and returns a JSON summary."""
+    args = parse_args(argv)
+    use_compile_cache()
+    tracer = None
+    if args.trace:
+        # install before any instrumented object exists: the engine,
+        # scheduler, and kernel registry capture the global tracer
+        tracer = obs.install_tracer(obs.Tracer())
+    served = build(args)
+    cfg, model, params, plan = (served.cfg, served.model, served.params,
+                                served.plan)
+    tune_stats = served.tune_stats
 
     if args.engine:
         with kreg.record_dispatches() as dispatch_log:
             summary = engine_main(args, model, params, plan,
-                                  draft_params=draft_params,
-                                  draft_plan=draft_plan)
+                                  draft_params=served.draft_params,
+                                  draft_plan=served.draft_plan)
         summary["kernel_dispatch"] = kreg.dispatch_counts(dispatch_log)
         if tune_stats is not None:
             summary["autotune"] = tune_stats

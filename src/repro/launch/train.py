@@ -22,6 +22,7 @@ from repro.checkpoint import Checkpointer
 from repro.core.sod import SoDConfig, sodify_params
 from repro.data.pipeline import SyntheticLMData
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import LM
 from repro.optim import AdamW, AdamWConfig, cosine_schedule
 from repro.runtime.fault import FaultConfig, ResilientRunner
@@ -74,6 +75,7 @@ def main(argv=None):
                     help="write a counters/gauges/histograms metrics "
                          "snapshot to PATH")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     tracer = None
     if args.trace:

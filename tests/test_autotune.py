@@ -76,17 +76,47 @@ def test_sod_config_auto_dispatches_through_registry_cpu_and_interpret():
     np.testing.assert_allclose(np.asarray(y_int), want, atol=5e-4, rtol=1e-4)
 
 
-def test_tpu_cold_cache_restricted_to_partitionable():
-    """Cold-cache dispatch on a real TPU mesh must stay on impls XLA can
-    partition under pjit (pallas_call has no GSPMD rule); a tuned entry is
-    an explicit opt-in and still wins."""
+def test_tpu_cold_cache_restricted_to_partitionable(monkeypatch):
+    """Cold-cache TPU dispatch picks the Pallas kernel on one chip and on a
+    mesh-qualified key; only an unwrapped dispatch in a multi-device
+    process (where a pjit step could shard the weights, and pallas_call has
+    no GSPMD rule) stays on natively partitionable impls.  A tuned entry
+    still wins everywhere."""
+    monkeypatch.setattr(autotune, "tpu_device_kind", lambda: "TPU v5 lite")
     _, p = _packed()
     key = registry.problem_key(p, m=256, backend="tpu")
+    monkeypatch.setattr(registry.jax, "device_count", lambda *a: 1)
     impl, _ = registry.choose(key)
-    assert impl.spmd_partitionable
+    assert impl.name == "pallas_fused"
+    wrapped = registry.problem_key(p, m=256, backend="tpu",
+                                   mesh="data=2,model=2|dp=data")
+    assert registry.choose(wrapped)[0].name == "pallas_fused"
+    impl_tuned, _ = registry.choose(
+        key, tuned={"impl": "jnp_oracle", "params": {}})
+    assert impl_tuned.name == "jnp_oracle"
+
+    monkeypatch.setattr(registry.jax, "device_count", lambda *a: 4)
+    impl, _ = registry.choose(key)
+    assert impl.spmd_partitionable and not impl.requires_shard_map
+    assert registry.choose(wrapped)[0].name == "pallas_fused"
     impl_tuned, _ = registry.choose(
         key, tuned={"impl": "pallas_fused", "params": {}})
     assert impl_tuned.name == "pallas_fused"
+
+
+def test_tpu_prior_needs_known_device_kind(monkeypatch):
+    """The TPU prior reads the chip's peaks from core.topology by
+    device_kind: an unknown kind raises, and a host with no TPU cannot rank
+    a TPU key at all — there is no default chip."""
+    _, p = _packed()
+    key = registry.problem_key(p, m=8, backend="tpu")
+    with pytest.raises(RuntimeError, match="needs a TPU device"):
+        autotune.rank_candidates(key)
+    monkeypatch.setattr(autotune, "tpu_device_kind", lambda: "TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        autotune.rank_candidates(key)
+    monkeypatch.setattr(autotune, "tpu_device_kind", lambda: "TPU v5 lite")
+    assert autotune.rank_candidates(key)
 
 
 def test_every_capable_impl_matches_ref():

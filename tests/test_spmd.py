@@ -208,10 +208,12 @@ def test_mesh_dispatch_uses_pallas_not_oracle(interpret_backend):
 
 
 @needs_mesh
-def test_tpu_cold_cache_promotes_pallas_only_inside_wrapper():
-    """The cold-cache TPU guard still pins *unwrapped* dispatch to natively
-    partitionable impls, but the mesh-qualified key (inside shard_map)
-    promotes the pallas kernels."""
+def test_tpu_cold_cache_promotes_pallas_only_inside_wrapper(monkeypatch):
+    """In a process that sees several devices the cold-cache TPU guard
+    still pins *unwrapped* dispatch to natively partitionable impls, but
+    the mesh-qualified key (inside shard_map) promotes the pallas kernels,
+    and so does a process that sees one chip."""
+    monkeypatch.setattr(autotune, "tpu_device_kind", lambda: "TPU v5 lite")
     _, p = _packed(seed=7)
     unwrapped, _ = registry.choose(
         registry.problem_key(p, m=64, backend="tpu"))
@@ -219,6 +221,10 @@ def test_tpu_cold_cache_promotes_pallas_only_inside_wrapper():
     wrapped, _ = registry.choose(
         registry.problem_key(p, m=64, backend="tpu", mesh="data=4|dp=data"))
     assert wrapped.name == "pallas_fused"
+    monkeypatch.setattr(registry.jax, "device_count", lambda *a: 1)
+    one_chip, _ = registry.choose(
+        registry.problem_key(p, m=64, backend="tpu"))
+    assert one_chip.name == "pallas_fused"
 
 
 @needs_mesh
