@@ -1,4 +1,4 @@
-"""Zero-dependency observability: tracing spans + a metrics registry.
+"""Observability: tracing spans, a metrics registry, a per-step phase log.
 
 The package has two halves:
 
@@ -6,18 +6,22 @@ The package has two halves:
   spans, instant events, and counter samples into a bounded ring buffer
   and exports Chrome trace-event JSON (loadable in Perfetto or
   ``chrome://tracing``).  A process-global tracer is installed with
-  :func:`install_tracer`; the default is a no-op ``NullTracer`` so that
-  instrumented code paths cost one attribute lookup when tracing is off.
+  :func:`install_tracer`; the default is ``NullTracer``, which records
+  nothing itself.  Spans of either tracer also enter a
+  ``jax.profiler.TraceAnnotation``, so they land on the profiler's host
+  plane beside the device's events whenever ``jax.profiler`` traces.
 * :mod:`repro.obs.metrics` — a ``Metrics`` registry of counters, gauges,
-  and fixed log-bucket ``Histogram`` objects with p50/p90/p99 summaries.
+  and fixed log-bucket ``Histogram`` objects with p50/p90/p99 summaries,
+  and ``Metrics.steps``, a bounded ``StepLog`` of per-step phase records.
   ``Metrics.stats_view()`` exposes the counter table as a plain mutable
   mapping so existing ``stats`` dicts can migrate onto it unchanged.
 
-Everything here is stdlib-only; see ``docs/observability.md`` for the
-span/track taxonomy and the metric glossary.
+``metrics`` is stdlib-only; ``tracer`` imports ``jax.profiler``.  See
+``docs/observability.md`` for the span/track taxonomy, the step log and
+the metric glossary.
 """
 
-from repro.obs.metrics import Histogram, Metrics
+from repro.obs.metrics import Histogram, Metrics, StepLog, StepRecord
 from repro.obs.tracer import (
     NULL_TRACER,
     NullTracer,
@@ -31,6 +35,8 @@ __all__ = [
     "Metrics",
     "NULL_TRACER",
     "NullTracer",
+    "StepLog",
+    "StepRecord",
     "Tracer",
     "get_tracer",
     "install_tracer",

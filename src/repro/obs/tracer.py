@@ -4,7 +4,12 @@ A :class:`Tracer` records three kinds of events into a bounded ring
 buffer, each tagged with a *track* (rendered as one timeline row):
 
 * **spans** — ``with tracer.span("decode", track="engine"): ...`` emits a
-  ``B``/``E`` pair; spans nest LIFO per track.
+  ``B``/``E`` pair; spans nest LIFO per track.  Every span, on the no-op
+  tracer too, also enters a :class:`jax.profiler.TraceAnnotation` of the
+  same name and args, so under ``jax.profiler`` it lands on the host plane
+  of the ``.xplane.pb`` on the device events' clock (about a microsecond
+  per span when the profiler is off).  ``begin``/``end`` pairs, which may
+  cross steps (slot residency), stay in the ring only.
 * **instants** — ``tracer.instant(...)`` emits a zero-duration ``i``
   event (e.g. a SeqPhase transition or a kernel dispatch).
 * **counters** — ``tracer.counter("pool_pages", {"free": 3, ...})``
@@ -19,10 +24,11 @@ Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  Any span
 still open at export time is closed at the export timestamp so every
 ``B`` has a matching ``E``.
 
-The module keeps a process-global tracer (default: the shared no-op
-:data:`NULL_TRACER`) behind :func:`get_tracer` / :func:`install_tracer`;
-instrumentation sites fetch it once and pay only a no-op method call
-when tracing is disabled.
+The module keeps a process-global tracer (default: the shared
+:data:`NULL_TRACER`, which records nothing itself) behind
+:func:`get_tracer` / :func:`install_tracer`; instrumentation sites fetch
+it once and, when the recorder is off, pay an empty method call or, for
+a span, the profiler annotation.
 """
 
 from __future__ import annotations
@@ -33,6 +39,8 @@ import threading
 import time
 from collections import deque
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 _PID = "repro"
 
@@ -48,34 +56,21 @@ def _clean(args: dict[str, Any]) -> dict[str, Any]:
     return out
 
 
-class _NullSpan:
-    """Context manager that does nothing; shared by all NullTracer spans."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """No-op tracer: every method returns immediately.
+    """Tracer that records nothing of its own.
 
-    Installed by default so instrumented code paths cost one attribute
-    lookup plus an empty call when tracing is off.  ``enabled`` is
-    ``False`` so hot paths can skip building event arguments entirely.
+    Installed by default: its spans are bare profiler annotations (about
+    a microsecond each while the profiler is off) and its other methods
+    are empty calls.  ``enabled`` is ``False`` so hot paths can skip
+    building event arguments entirely.
     """
 
     enabled = False
 
     def span(self, name, track="engine", cat=None, **args):
-        """Return the shared no-op context manager."""
-        return _NULL_SPAN
+        """Return a profiler annotation ``name`` (recorded only while
+        ``jax.profiler`` traces)."""
+        return TraceAnnotation(name, **args)
 
     def begin(self, name, track="engine", cat=None, **args):
         """No-op."""
@@ -98,9 +93,10 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager emitting a ``B`` on enter and ``E`` on exit."""
+    """Context manager emitting a ``B`` on enter and ``E`` on exit, with a
+    profiler annotation of the same name and args inside them."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args")
+    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args", "_ann")
 
     def __init__(self, tracer, name, track, cat, args):
         self._tracer = tracer
@@ -108,12 +104,15 @@ class _Span:
         self._track = track
         self._cat = cat
         self._args = args
+        self._ann = TraceAnnotation(name, **args)
 
     def __enter__(self):
         self._tracer.begin(self._name, self._track, self._cat, **self._args)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         self._tracer.end(self._name, self._track, self._cat)
         return False
 

@@ -505,24 +505,28 @@ class Engine:
     def _admit_paged(self, req: Request) -> list[tuple[int, int]]:
         plen = len(req.tokens)
         bucket = self._bucket(plen)
-        padded = np.zeros(bucket, np.int32)
-        padded[:plen] = req.tokens
-        logits, cache = self._prefill(
-            self.params, {"tokens": jnp.asarray(padded)[None]})
-        first = int(jnp.argmax(logits[0, plen - 1]))
-        n = self.page_pool.pages_for(bucket)
-        pages = self.page_pool.alloc(n)
-        self.pool = self._page_write(
-            self.pool, cache, jnp.asarray(np.asarray(pages, np.int32)))
+        with self._phase("prefill"):
+            padded = np.zeros(bucket, np.int32)
+            padded[:plen] = req.tokens
+            logits, cache = self._prefill(
+                self.params, {"tokens": jnp.asarray(padded)[None]})
+        with self._phase("prefill.wait"):
+            first = int(jnp.argmax(logits[0, plen - 1]))
+        with self._phase("page_write"):
+            n = self.page_pool.pages_for(bucket)
+            pages = self.page_pool.alloc(n)
+            self.pool = self._page_write(
+                self.pool, cache, jnp.asarray(np.asarray(pages, np.int32)))
         if self.spec_k:
             # the draft tier needs its own prompt KV: same pages, its own
             # pool, its own (cheaper) weights.  Draft logits are unused —
             # the first token must be the target's.
-            _, dcache = self._draft_prefill(
-                self.draft_params, {"tokens": jnp.asarray(padded)[None]})
-            self.draft_pool = self._page_write(
-                self.draft_pool, dcache,
-                jnp.asarray(np.asarray(pages, np.int32)))
+            with self._phase("draft.prefill"):
+                _, dcache = self._draft_prefill(
+                    self.draft_params, {"tokens": jnp.asarray(padded)[None]})
+                self.draft_pool = self._page_write(
+                    self.draft_pool, dcache,
+                    jnp.asarray(np.asarray(pages, np.int32)))
         seq = self.sched.place(req, pos=plen, first_token=first, pages=pages,
                                ready_wall=self._first_seen[req.rid])
         self.block_tables[seq.slot, :] = PagePool.TRASH_PAGE
@@ -535,7 +539,8 @@ class Engine:
         """Snapshot one page's KV bytes to host numpy arrays — the cache's
         demotion path (same per-page movement preemption's swap uses)."""
         snap = self._page_get(self.pool, jnp.asarray(page, jnp.int32))
-        return jax.device_get(snap)
+        with self._phase("demote.wait"):
+            return jax.device_get(snap)
 
     def _restore_prefix(self, keys: list[str]) -> list[int]:
         """Promote cached chunks back into HBM: allocate one fresh page
@@ -625,13 +630,15 @@ class Engine:
 
     def _admit_state(self, req: Request) -> list[tuple[int, int]]:
         prompt = jnp.asarray(req.tokens, jnp.int32)[None]
-        sub = self.model.init_cache(1, self.max_len)
-        nxt = None
-        for t in range(prompt.shape[1]):
-            nxt, _, sub = self._decode(
-                self.params, sub, prompt[:, t:t + 1],
-                jnp.asarray(t, jnp.int32))
-        first = int(np.asarray(nxt).reshape(-1)[0])
+        with self._phase("prefill"):
+            sub = self.model.init_cache(1, self.max_len)
+            nxt = None
+            for t in range(prompt.shape[1]):
+                nxt, _, sub = self._decode(
+                    self.params, sub, prompt[:, t:t + 1],
+                    jnp.asarray(t, jnp.int32))
+        with self._phase("prefill.wait"):
+            first = int(np.asarray(nxt).reshape(-1)[0])
         seq = self.sched.place(req, pos=prompt.shape[1], first_token=first,
                                pages=[],
                                ready_wall=self._first_seen[req.rid])
@@ -766,7 +773,8 @@ class Engine:
                                end // self.page_size)
         if end < plen:
             return []
-        first = int(jnp.argmax(logits[0, plen - 1 - start]))
+        with self._phase("prefill.wait"):
+            first = int(jnp.argmax(logits[0, plen - 1 - start]))
         seq.generated.append(first)
         seq.pos = plen
         self.sched.set_phase(seq, SeqPhase.DECODING)
@@ -805,8 +813,9 @@ class Engine:
                 self.stats["spec_window_preemptions"] += 1
                 self.stats["spec_rollback_pages"] += len(freed)
         n = len(seq.pages)
-        host = jax.device_get(
-            self._gather_pages(self.pool, self._padded_ids(seq.pages)))
+        snap = self._gather_pages(self.pool, self._padded_ids(seq.pages))
+        with self._phase("swap_out.wait"):
+            host = jax.device_get(snap)
         seq.host_kv = (host, n)
         freed = self.page_pool.swap_out(seq.pages)
         if self.trie is not None:
@@ -937,29 +946,36 @@ class Engine:
         # backfills draft KV for position pos + k, which full acceptance
         # commits without another draft read of it this window — skipping
         # it leaves stale pad KV behind the next window's proposals
-        with self.tracer.span("draft", track="spec", k=k,
-                              slots=len(decoding)):
+        with self._phase("draft"):
             for j in range(k + 1):
                 nxt, _, self.draft_pool = self._draft_decode(
                     self.draft_params, self.draft_pool, btj,
                     jnp.asarray(d_tok), jnp.asarray(d_pos), valid)
                 if j == k:
                     break
-                col = np.asarray(nxt).reshape(self.max_slots, -1)[:, 0]
+                with self._phase("draft.wait"):
+                    col = np.asarray(nxt).reshape(self.max_slots, -1)[:, 0]
                 drafts[:, j] = col
                 d_tok[:, 0] = col
                 d_pos += 1
 
-        v_tok = np.zeros((self.max_slots, k + 1), np.int32)
-        v_tok[:, 0] = self._tok[:, 0]
-        v_tok[:, 1:] = drafts
-        with self.tracer.span("verify", track="spec", k=k,
-                              slots=len(decoding)):
+        with self._phase("verify"):
+            v_tok = np.zeros((self.max_slots, k + 1), np.int32)
+            v_tok[:, 0] = self._tok[:, 0]
+            v_tok[:, 1:] = drafts
             nxt, _, self.pool = self._verify(
                 self.params, self.pool, btj, jnp.asarray(v_tok),
                 jnp.asarray(self._pos), valid)
+        with self._phase("verify.wait"):
             target = np.asarray(nxt).reshape(self.max_slots, k + 1)
+        with self._phase("commit"):
+            return self._spec_commit(decoding, drafts, target)
 
+    def _spec_commit(self, decoding: dict[int, SeqState], drafts: np.ndarray,
+                     target: np.ndarray) -> list[tuple[int, int]]:
+        """Accept each slot's longest matching draft prefix plus one bonus
+        target token, and roll back the pages of rejected positions."""
+        k = self.spec_k
         events: list[tuple[int, int]] = []
         for slot, seq in list(decoding.items()):
             m = 0
@@ -984,20 +1000,33 @@ class Engine:
         return events
 
     # -- stepping: the per-step phase pipeline --------------------------------
-    def _phase_admission(self, now: int) -> list[tuple[int, int]]:
+    def _phase(self, name: str):
+        """Context manager around one phase of the current step: a tracer
+        span ``engine.step.<name>`` (a profiler annotation at least) and
+        the phase's seconds in the step log, ``self.metrics.steps``."""
+        return self.metrics.steps.phase(
+            self.tracer.span(f"engine.step.{name}", track="engine"), name)
+
+    def _phase_admission(self, now: int,
+                         rec: obs.StepRecord) -> list[tuple[int, int]]:
         """Admission phase: resume swapped sequences first (they were
         admitted before anyone still pending), then admit queue heads
         while a slot and pages are free.  Fused-prefill admission emits
         the first token immediately; chunked admission places the slot in
-        the prefilling phase for :meth:`_phase_prefill` to advance."""
+        the prefilling phase for :meth:`_phase_prefill` to advance.
+        Counts the admitted and the still-waiting admissible requests
+        into the step's record ``rec``."""
         now_wall = time.perf_counter()
         # latency clock starts when a request becomes admissible, not when
         # it reaches the queue head — queue wait is part of tail latency
+        ready = 0
         for r in self.sched.pending:
             if r.arrival > now:
                 break                        # pending is arrival-sorted
             self._first_seen.setdefault(r.rid, now_wall)
+            ready += 1
         events: list[tuple[int, int]] = []
+        admitted = 0
         if self.paged:
             # swapped sequences were admitted first: resume before anyone
             while self.sched.swapped and self.sched.has_free_slot():
@@ -1024,6 +1053,9 @@ class Engine:
                     events += self._admit_paged(req)
             else:
                 events += self._admit_state(req)
+            admitted += 1
+        rec.admitted = admitted
+        rec.queue_ready = ready - admitted
         return events
 
     def _phase_prefill(self) -> list[tuple[int, int]]:
@@ -1043,25 +1075,32 @@ class Engine:
         Prefilling/idle rows ride along with write cutoff 0 (paged) or an
         untouched slot cache (recurrent)."""
         events: list[tuple[int, int]] = []
-        tok = jnp.asarray(self._tok)
-        pos = jnp.asarray(self._pos)
-        if self.paged:
-            nxt, _, self.pool = self._decode(
-                self.params, self.pool, jnp.asarray(self.block_tables),
-                tok, pos, jnp.asarray(self._valid_lens()))
-        else:
-            nxt, _, self.cache = self._decode(
-                self.params, self.cache, tok, pos)
-        nxt = np.asarray(nxt).reshape(self.max_slots, -1)[:, 0]
-        for slot, seq in list(decoding.items()):
-            t = int(nxt[slot])
-            seq.generated.append(t)
-            seq.pos += 1
-            self._pos[slot] = seq.pos
-            self._tok[slot, 0] = t
-            events.append((seq.req.rid, t))
-            if seq.remaining == 0:
-                self._complete(slot)
+        with self._phase("decode.prepare"):
+            tok = jnp.asarray(self._tok)
+            pos = jnp.asarray(self._pos)
+            if self.paged:
+                tables = jnp.asarray(self.block_tables)
+                valid = jnp.asarray(self._valid_lens())
+        with self._phase("decode.dispatch"):
+            if self.paged:
+                nxt, _, self.pool = self._decode(
+                    self.params, self.pool, tables, tok, pos, valid)
+            else:
+                nxt, _, self.cache = self._decode(
+                    self.params, self.cache, tok, pos)
+        with self._phase("decode.wait"):
+            nxt = np.asarray(nxt)
+        with self._phase("commit"):
+            nxt = nxt.reshape(self.max_slots, -1)[:, 0]
+            for slot, seq in list(decoding.items()):
+                t = int(nxt[slot])
+                seq.generated.append(t)
+                seq.pos += 1
+                self._pos[slot] = seq.pos
+                self._tok[slot, 0] = t
+                events.append((seq.req.rid, t))
+                if seq.remaining == 0:
+                    self._complete(slot)
         return events
 
     def step(self) -> list[tuple[int, int]]:
@@ -1071,28 +1110,38 @@ class Engine:
         verify/decode → commit/rollback.  Feature flags select phase
         implementations — every combination of chunked prefill,
         preemption, prefix sharing, and speculative decoding runs through
-        this one pipeline.  Returns (rid, token) emissions."""
-        tr = self.tracer
-        with tr.span("step", track="engine", step=self._step_idx):
-            with tr.span("admission", track="engine"):
-                events = self._phase_admission(self._step_idx)
+        this one pipeline.  Returns (rid, token) emissions.
+
+        The step is a span ``engine.step`` and each phase a nested span
+        ``engine.step.<phase>`` (see :meth:`_phase`); the step's record in
+        ``self.metrics.steps`` gets each phase's seconds and what the step
+        admitted, decoded and completed.  The ``*.wait`` phases wrap the
+        step's existing host-device syncs and add none."""
+        now = self._step_idx
+        finished = len(self._finished)
+        with self.tracer.span("engine.step", track="engine", step=now), \
+                self.metrics.steps.step(now) as rec:
+            with self._phase("admission"):
+                events = self._phase_admission(now, rec)
             if self.paged:
                 if self.prefill_chunk:
-                    with tr.span("prefill", track="engine"):
+                    with self._phase("prefill"):
                         events += self._phase_prefill()
-                with tr.span("capacity", track="engine"):
+                with self._phase("capacity"):
                     self._phase_capacity()
             decoding = {slot: seq for slot, seq in self.sched.active.items()
                         if seq.phase is SeqPhase.DECODING}
             if decoding:
+                rec.decode_rows = len(decoding)
                 if self.spec_k:
-                    with tr.span("spec_window", track="engine"):
-                        events += self._spec_window(decoding)
+                    events += self._spec_window(decoding)
                 else:
-                    with tr.span("decode", track="engine"):
-                        events += self._phase_decode(decoding)
+                    events += self._phase_decode(decoding)
             if self.paged:
-                self._sample_pool()
+                with self._phase("pool_sample"):
+                    self._sample_pool()
+                rec.free_pages = self.page_pool.free_count
+            rec.completed = len(self._finished) - finished
         self._step_idx += 1
         return events
 

@@ -204,7 +204,7 @@ def test_engine_tokens_identical_traced_vs_untraced(tmp_path):
     assert {"engine", "lifecycle", "pool"} <= tracks
     assert any(t.startswith("slot") for t in tracks)
     steps = [e for e in events
-             if e["name"] == "step" and e["ph"] == "B"]
+             if e["name"] == "engine.step" and e["ph"] == "B"]
     assert len(steps) == res_on["stats"]["steps"]
     reqs = {e["name"] for e in events if e.get("cat") == "request"}
     assert reqs == {"req0", "req1", "req2"}
@@ -213,7 +213,7 @@ def test_engine_tokens_identical_traced_vs_untraced(tmp_path):
     trp = _load_trace_report()
     rep = trp.report(out, track="engine")
     assert rep["events"] == len(events)
-    assert any(k.endswith(":step") for k in rep["spans"])
+    assert any(k.endswith(":engine.step") for k in rep["spans"])
     assert {r["request"] for r in rep["slowest_requests"]} == reqs
     assert trp.main([str(out), "--track", "engine"]) == 0
 
