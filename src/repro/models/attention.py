@@ -272,6 +272,40 @@ def init_paged_pool(n_pages: int, page_size: int, spec: AttnSpec,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def paged_kv(pool: Params, layer, page: jax.Array, off: jax.Array,
+             k_new: jax.Array, v_new: jax.Array, block_tables: jax.Array):
+    """Write one layer's new KV rows into the pool, then gather the layer's
+    rows back position-ordered.  Every paged attention call touches the
+    pool through here.
+
+    ``pool`` arrays are (..., n_pages, page, KV, hd): one layer's pool, or
+    a stack of every layer's pool (the transformer's).  The leading layer
+    axes fold into the page axis by a reshape (a bitcast: only major axes
+    merge, the heads axis never moves) and ``layer`` — the flat layer index
+    over them, 0 for a single layer — offsets every page id.  So neither the
+    scatter nor the gather slices the layer's pool out, and a pool carried
+    through a layer scan (and donated by the program) is updated in place.
+
+    ``page``/``off`` name the N written rows; ``k_new``/``v_new`` are
+    (N, KV, hd).  Returns (pool, k_cache, v_cache), the caches
+    (B, max_pages·page, KV, hd).
+    """
+    n_pages = pool["k"].shape[-4]
+    page = layer * n_pages + page
+    rows = layer * n_pages + block_tables
+    b = block_tables.shape[0]
+
+    def update(a, new):
+        flat = a.reshape((-1,) + a.shape[-3:])
+        flat = flat.at[page, off].set(new)
+        cache = flat[rows].reshape(b, -1, *a.shape[-2:])
+        return flat.reshape(a.shape), cache
+
+    k_pool, k_cache = update(pool["k"], k_new)
+    v_pool, v_cache = update(pool["v"], v_new)
+    return {"k": k_pool, "v": v_pool}, k_cache, v_cache
+
+
 def paged_prefill_attention(
     params: Params,
     x: jax.Array,              # (B, C, D) — one chunk of prompt tokens
@@ -281,6 +315,7 @@ def paged_prefill_attention(
     valid_len: jax.Array,      # scalar: prompt length (pad cutoff)
     spec: AttnSpec,
     window: int | None = None,
+    layer=0,
 ):
     """Chunked-prefill attention: C prompt positions against the pool.
 
@@ -297,7 +332,8 @@ def paged_prefill_attention(
     already relies on): one :func:`_online_block` update over the gathered
     keys, where positions outside a row's mask contribute exact zeros.
     Rows are position-independent, so the chunk split itself never changes
-    a token.
+    a token.  ``pool`` and ``layer`` are as in :func:`paged_kv`; the whole
+    pool comes back.
     """
     b, c, _ = x.shape
     start = jnp.asarray(start, jnp.int32)
@@ -305,7 +341,7 @@ def paged_prefill_attention(
     idx = start + jnp.arange(c, dtype=jnp.int32)            # (C,)
     positions = jnp.broadcast_to(idx, (b, c))
     q, k_new, v_new = _project_qkv(params, x, spec, positions)
-    page_size = pool["k"].shape[1]
+    page_size = pool["k"].shape[-3]
     kvh = spec.n_kv_heads
     g = spec.n_heads // kvh
     hd = spec.head_dim
@@ -314,13 +350,10 @@ def paged_prefill_attention(
         block_tables, jnp.broadcast_to(idx // page_size, (b, c)), axis=1)
     page = jnp.where((idx < valid_len)[None, :], page, 0)   # pad → trash
     off = jnp.broadcast_to(idx % page_size, (b, c))
-    k_pool = pool["k"].at[page.reshape(-1), off.reshape(-1)].set(
-        k_new.reshape(b * c, kvh, hd))
-    v_pool = pool["v"].at[page.reshape(-1), off.reshape(-1)].set(
-        v_new.reshape(b * c, kvh, hd))
-
-    k_cache = k_pool[block_tables].reshape(b, -1, kvh, hd)
-    v_cache = v_pool[block_tables].reshape(b, -1, kvh, hd)
+    pool, k_cache, v_cache = paged_kv(
+        pool, layer, page.reshape(-1), off.reshape(-1),
+        k_new.reshape(b * c, kvh, hd), v_new.reshape(b * c, kvh, hd),
+        block_tables)
     s_max = k_cache.shape[1]
 
     qh = q.reshape(b, c, kvh, g, hd)
@@ -339,7 +372,7 @@ def paged_prefill_attention(
     out = acc / jnp.maximum(l, 1e-30)[..., None]            # (B,KV,G,C,hd)
     out = out.transpose(0, 3, 1, 2, 4).reshape(b, c, spec.n_heads * hd)
     out = out.astype(x.dtype)
-    return out, {"k": k_pool, "v": v_pool}
+    return out, pool
 
 
 def paged_verify_attention(
@@ -351,6 +384,7 @@ def paged_verify_attention(
     valid_len: jax.Array,      # (B,) per-row write cutoff (seq end)
     spec: AttnSpec,
     window: int | None = None,
+    layer=0,
 ):
     """Speculative-decoding verification: C positions per row, decode
     numerics.
@@ -366,14 +400,14 @@ def paged_verify_attention(
     online-softmax probabilities — so only this shape is bitwise identical
     to running :func:`paged_decode_attention` sequentially over the same
     tokens, which is what makes accepted speculative tokens exactly the
-    greedy sequence.
+    greedy sequence.  ``pool`` and ``layer`` are as in :func:`paged_kv`.
     """
     b, c, _ = x.shape
     start = jnp.asarray(start, jnp.int32).reshape(b)
     valid_len = jnp.asarray(valid_len, jnp.int32).reshape(b)
     idx = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]  # (B,C)
     q, k_new, v_new = _project_qkv(params, x, spec, idx)
-    page_size = pool["k"].shape[1]
+    page_size = pool["k"].shape[-3]
     kvh = spec.n_kv_heads
     g = spec.n_heads // kvh
     hd = spec.head_dim
@@ -381,13 +415,10 @@ def paged_verify_attention(
     page = jnp.take_along_axis(block_tables, idx // page_size, axis=1)
     page = jnp.where(idx < valid_len[:, None], page, 0)     # overflow → trash
     off = idx % page_size
-    k_pool = pool["k"].at[page.reshape(-1), off.reshape(-1)].set(
-        k_new.reshape(b * c, kvh, hd))
-    v_pool = pool["v"].at[page.reshape(-1), off.reshape(-1)].set(
-        v_new.reshape(b * c, kvh, hd))
-
-    k_cache = k_pool[block_tables].reshape(b, -1, kvh, hd)
-    v_cache = v_pool[block_tables].reshape(b, -1, kvh, hd)
+    pool, k_cache, v_cache = paged_kv(
+        pool, layer, page.reshape(-1), off.reshape(-1),
+        k_new.reshape(b * c, kvh, hd), v_new.reshape(b * c, kvh, hd),
+        block_tables)
     s_max = k_cache.shape[1]
 
     qh = q.reshape(b, c, kvh, g, hd)
@@ -403,7 +434,7 @@ def paged_verify_attention(
         preferred_element_type=jnp.float32,
     )
     out = out.reshape(b, c, spec.n_heads * hd).astype(x.dtype)
-    return sod.apply(out, params["wo"]), {"k": k_pool, "v": v_pool}
+    return sod.apply(out, params["wo"]), pool
 
 
 def paged_decode_attention(
@@ -415,6 +446,7 @@ def paged_decode_attention(
     spec: AttnSpec,
     window: int | None = None,
     valid_len: jax.Array | None = None,
+    layer=0,
 ):
     """One decode step against the paged KV pool.
 
@@ -432,24 +464,22 @@ def paged_decode_attention(
     host, and to keep draft steps probing past a sequence's end from
     dirtying a live page.  Reads are unaffected — the attention mask
     already scopes each row to ``<= pos``.
+
+    ``pool`` and ``layer`` are as in :func:`paged_kv`; the whole pool
+    comes back.
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     q, k_new, v_new = _project_qkv(params, x, spec, _decode_positions(pos, b))
-    page_size = pool["k"].shape[1]
+    page_size = pool["k"].shape[-3]
     page = jnp.take_along_axis(
         block_tables, (pos // page_size)[:, None], axis=1)[:, 0]
     if valid_len is not None:
         valid_len = jnp.asarray(valid_len, jnp.int32).reshape(b)
         page = jnp.where(pos < valid_len, page, 0)      # overflow → trash
     off = pos % page_size
-    k_pool = pool["k"].at[page, off].set(k_new[:, 0])
-    v_pool = pool["v"].at[page, off].set(v_new[:, 0])
-    # gather: (B, max_pages, page, KV, hd) → position-ordered (B, L, KV, hd)
-    k_cache = k_pool[block_tables].reshape(
-        b, -1, spec.n_kv_heads, spec.head_dim)
-    v_cache = v_pool[block_tables].reshape(
-        b, -1, spec.n_kv_heads, spec.head_dim)
+    pool, k_cache, v_cache = paged_kv(pool, layer, page, off, k_new[:, 0],
+                                      v_new[:, 0], block_tables)
     out = _attend_cached(q, k_cache, v_cache, pos, spec, window)
     out = out.astype(x.dtype)
-    return sod.apply(out, params["wo"]), {"k": k_pool, "v": v_pool}
+    return sod.apply(out, params["wo"]), pool

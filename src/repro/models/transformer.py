@@ -20,6 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import plan as plan_mod
@@ -159,12 +160,12 @@ def attn_block_decode(bp: Params, x: jax.Array, cache: Params,
                       pos: jax.Array, cfg: ModelConfig,
                       window: int | None,
                       block_tables: jax.Array | None = None,
-                      valid_len: jax.Array | None = None):
+                      valid_len: jax.Array | None = None, layer=0):
     """One decode block.  ``cache`` is a dense per-slot KV cache, or —
-    when ``block_tables`` is given — this layer's slice of the paged KV
-    pool (the engine's slot→page mapping).  ``valid_len`` (paged only)
-    is the optional per-row write cutoff forwarded to
-    :func:`repro.models.attention.paged_decode_attention`."""
+    when ``block_tables`` is given — the paged KV pool (the engine's
+    slot→page mapping), of which this block is flat layer ``layer``.
+    ``valid_len`` (paged only) is the optional per-row write cutoff
+    forwarded to :func:`repro.models.attention.paged_decode_attention`."""
     spec = attn_spec(cfg)
     h = layers.rms_norm(x, bp["norm1"], cfg.norm_eps)
     if block_tables is None:
@@ -173,7 +174,7 @@ def attn_block_decode(bp: Params, x: jax.Array, cache: Params,
     else:
         ao, cache = attn.paged_decode_attention(
             bp["attn"], h, cache, block_tables, pos, spec, window=window,
-            valid_len=valid_len)
+            valid_len=valid_len, layer=layer)
     if cfg.use_post_norms:
         ao = layers.rms_norm(ao, bp["norm1_post"], cfg.norm_eps)
     x = x + ao
@@ -184,10 +185,10 @@ def attn_block_decode(bp: Params, x: jax.Array, cache: Params,
     return x + mo, cache
 
 
-def attn_block_verify(bp: Params, x: jax.Array, layer_pool: Params,
+def attn_block_verify(bp: Params, x: jax.Array, pool: Params,
                       block_tables: jax.Array, start: jax.Array,
                       valid_len: jax.Array, cfg: ModelConfig,
-                      window: int | None):
+                      window: int | None, layer=0):
     """One block over a speculative verification window.
 
     Mirrors :func:`attn_block_decode`'s paged branch exactly (same norm /
@@ -201,9 +202,9 @@ def attn_block_verify(bp: Params, x: jax.Array, layer_pool: Params,
     """
     spec = attn_spec(cfg)
     h = layers.rms_norm(x, bp["norm1"], cfg.norm_eps)
-    ao, layer_pool = attn.paged_verify_attention(
-        bp["attn"], h, layer_pool, block_tables, start, valid_len, spec,
-        window=window)
+    ao, pool = attn.paged_verify_attention(
+        bp["attn"], h, pool, block_tables, start, valid_len, spec,
+        window=window, layer=layer)
     if cfg.use_post_norms:
         ao = layers.rms_norm(ao, bp["norm1_post"], cfg.norm_eps)
     x = x + ao
@@ -211,13 +212,13 @@ def attn_block_verify(bp: Params, x: jax.Array, layer_pool: Params,
     mo, _ = _apply_mlp(bp, h2, cfg)
     if cfg.use_post_norms:
         mo = layers.rms_norm(mo, bp["norm2_post"], cfg.norm_eps)
-    return x + mo, layer_pool
+    return x + mo, pool
 
 
-def attn_block_prefill_chunk(bp: Params, x: jax.Array, layer_pool: Params,
+def attn_block_prefill_chunk(bp: Params, x: jax.Array, pool: Params,
                              block_tables: jax.Array, start: jax.Array,
                              valid_len: jax.Array, cfg: ModelConfig,
-                             window: int | None):
+                             window: int | None, layer=0):
     """One block over a prefill chunk against the paged KV pool.
 
     Mirrors :func:`attn_block_full` (same ``wo`` plan entry, same norm /
@@ -227,9 +228,9 @@ def attn_block_prefill_chunk(bp: Params, x: jax.Array, layer_pool: Params,
     """
     spec = attn_spec(cfg)
     h = layers.rms_norm(x, bp["norm1"], cfg.norm_eps)
-    ao, layer_pool = attn.paged_prefill_attention(
-        bp["attn"], h, layer_pool, block_tables, start, valid_len, spec,
-        window=window)
+    ao, pool = attn.paged_prefill_attention(
+        bp["attn"], h, pool, block_tables, start, valid_len, spec,
+        window=window, layer=layer)
     ao = sod.apply(ao, bp["attn"]["wo"],
                    plan=plan_mod.active_entry("attn.wo"))
     if cfg.use_post_norms:
@@ -239,7 +240,7 @@ def attn_block_prefill_chunk(bp: Params, x: jax.Array, layer_pool: Params,
     mo, _ = _apply_mlp(bp, h2, cfg)
     if cfg.use_post_norms:
         mo = layers.rms_norm(mo, bp["norm2_post"], cfg.norm_eps)
-    return x + mo, layer_pool
+    return x + mo, pool
 
 
 # ---------------------------------------------------------------------------
@@ -413,6 +414,38 @@ def transformer_init_paged_pool(cfg: ModelConfig, n_pages: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
+def _paged_layers(params: Params, x: jax.Array, pool: Params,
+                  cfg: ModelConfig, block):
+    """Run every attention block against the stacked paged pool.
+
+    The pool rides in the layer scan's carry, never in its ``xs``/``ys``:
+    ``block(bp, x, pool, layer, window)`` writes and reads its layer
+    through :func:`repro.models.attention.paged_kv` at flat index
+    ``layer = g·P + j``, so no layer's pool is sliced out and stacked back
+    and a donated pool is updated in place.  The carry is the pool viewed
+    as (G·P, n_pages, ...) (a bitcast), so its layout has no unit axis for
+    the compiler to reorder inside the loop.  Unrolled
+    (``cfg.scan_layers=False``) the index is a static int.
+    """
+    p_period = cfg.pattern_period
+    n_groups = cfg.n_layers // p_period
+    shape = pool["k"].shape
+    pool = {k: a.reshape((-1,) + shape[2:]) for k, a in pool.items()}
+
+    def group_body(carry, inp):
+        x, pool = carry
+        gp, g = inp
+        for j in range(p_period):
+            bp = jax.tree_util.tree_map(lambda t: t[j], gp)
+            x, pool = block(bp, x, pool, g * p_period + j, cfg.window_for(j))
+        return (x, pool), None
+
+    (x, pool), _ = _scan(
+        group_body, (x, pool),
+        (params["blocks"], np.arange(n_groups, dtype=np.int32)), cfg)
+    return x, {k: a.reshape(shape) for k, a in pool.items()}
+
+
 def transformer_decode_paged(params: Params, pool: Params,
                              block_tables: jax.Array, tokens: jax.Array,
                              pos: jax.Array, cfg: ModelConfig,
@@ -421,31 +454,20 @@ def transformer_decode_paged(params: Params, pool: Params,
 
     ``pos`` is a (B,) vector — one position per engine slot.  Mirrors
     :func:`transformer_decode` with each layer's dense cache slice
-    replaced by its page pool + the shared block tables.  ``valid_len``
-    (optional, (B,)) gates each row's KV write: rows at or beyond their
-    cutoff write to the trash page, letting one batched step cover a mix
-    of decoding and prefilling/idle slots.
+    replaced by its layer of the page pool + the shared block tables.
+    ``valid_len`` (optional, (B,)) gates each row's KV write: rows at or
+    beyond their cutoff write to the trash page, letting one batched step
+    cover a mix of decoding and prefilling/idle slots.
     """
     x = embed_inputs(params, {"tokens": tokens}, cfg)
-    p_period = cfg.pattern_period
 
-    def group_body(x, inp):
-        gp, kp, vp = inp
-        ks, vs = [], []
-        for j in range(p_period):
-            bp = jax.tree_util.tree_map(lambda t: t[j], gp)
-            layer_pool = {"k": kp[j], "v": vp[j]}
-            x, layer_pool = attn_block_decode(
-                bp, x, layer_pool, pos, cfg, cfg.window_for(j),
-                block_tables=block_tables, valid_len=valid_len)
-            ks.append(layer_pool["k"])
-            vs.append(layer_pool["v"])
-        return x, (jnp.stack(ks), jnp.stack(vs))
+    def block(bp, x, pool, layer, window):
+        return attn_block_decode(bp, x, pool, pos, cfg, window,
+                                 block_tables=block_tables,
+                                 valid_len=valid_len, layer=layer)
 
-    x, (knew, vnew) = _scan(
-        group_body, x, (params["blocks"], pool["k"], pool["v"]), cfg)
-    logits = project_logits(params, x, cfg)
-    return logits, {"k": knew, "v": vnew}
+    x, pool = _paged_layers(params, x, pool, cfg, block)
+    return project_logits(params, x, cfg), pool
 
 
 def transformer_verify_chunk(params: Params, pool: Params,
@@ -464,25 +486,13 @@ def transformer_verify_chunk(params: Params, pool: Params,
     engine's accept rule relies on.
     """
     x = embed_inputs(params, {"tokens": tokens}, cfg)
-    p_period = cfg.pattern_period
 
-    def group_body(x, inp):
-        gp, kp, vp = inp
-        ks, vs = [], []
-        for j in range(p_period):
-            bp = jax.tree_util.tree_map(lambda t: t[j], gp)
-            layer_pool = {"k": kp[j], "v": vp[j]}
-            x, layer_pool = attn_block_verify(
-                bp, x, layer_pool, block_tables, start, valid_len, cfg,
-                cfg.window_for(j))
-            ks.append(layer_pool["k"])
-            vs.append(layer_pool["v"])
-        return x, (jnp.stack(ks), jnp.stack(vs))
+    def block(bp, x, pool, layer, window):
+        return attn_block_verify(bp, x, pool, block_tables, start, valid_len,
+                                 cfg, window, layer=layer)
 
-    x, (knew, vnew) = _scan(
-        group_body, x, (params["blocks"], pool["k"], pool["v"]), cfg)
-    logits = project_logits(params, x, cfg)
-    return logits, {"k": knew, "v": vnew}
+    x, pool = _paged_layers(params, x, pool, cfg, block)
+    return project_logits(params, x, cfg), pool
 
 
 def transformer_prefill_chunk(params: Params, pool: Params,
@@ -499,25 +509,13 @@ def transformer_prefill_chunk(params: Params, pool: Params,
     sequence's first generated token.
     """
     x = embed_inputs(params, {"tokens": tokens}, cfg)
-    p_period = cfg.pattern_period
 
-    def group_body(x, inp):
-        gp, kp, vp = inp
-        ks, vs = [], []
-        for j in range(p_period):
-            bp = jax.tree_util.tree_map(lambda t: t[j], gp)
-            layer_pool = {"k": kp[j], "v": vp[j]}
-            x, layer_pool = attn_block_prefill_chunk(
-                bp, x, layer_pool, block_tables, start, valid_len, cfg,
-                cfg.window_for(j))
-            ks.append(layer_pool["k"])
-            vs.append(layer_pool["v"])
-        return x, (jnp.stack(ks), jnp.stack(vs))
+    def block(bp, x, pool, layer, window):
+        return attn_block_prefill_chunk(bp, x, pool, block_tables, start,
+                                        valid_len, cfg, window, layer=layer)
 
-    x, (knew, vnew) = _scan(
-        group_body, x, (params["blocks"], pool["k"], pool["v"]), cfg)
-    logits = project_logits(params, x, cfg)
-    return logits, {"k": knew, "v": vnew}
+    x, pool = _paged_layers(params, x, pool, cfg, block)
+    return project_logits(params, x, cfg), pool
 
 
 # ---------------------------------------------------------------------------

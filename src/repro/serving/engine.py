@@ -144,29 +144,35 @@ def bucket_len(plen: int, page_size: int, chunk: int | None = None) -> int:
     return b
 
 
+def _set_pages(a: jax.Array, page_ids, pages: jax.Array) -> jax.Array:
+    """Write ``pages`` at ``page_ids`` of every layer of the stacked pool
+    side ``a`` (G, P, n_pages, page, KV, hd).  ``pages`` holds each layer's
+    pages in order, (G, P, n_ids, ...) or any shape that reshapes to
+    (G·P·n_ids, page, KV, hd).  One scatter along the leading axis of the
+    pool viewed as (G·P·n_pages, page, KV, hd) (a bitcast), so a donated
+    pool is written in place."""
+    g, p, n = a.shape[:3]
+    ids = jnp.asarray(page_ids, jnp.int32).reshape(1, -1)
+    rows = (jnp.arange(g * p, dtype=jnp.int32)[:, None] * n + ids).reshape(-1)
+    flat = a.reshape((-1,) + a.shape[3:])
+    flat = flat.at[rows].set(pages.reshape((-1,) + a.shape[3:]))
+    return flat.reshape(a.shape)
+
+
 def _pool_write_pages(pool: Params, cache: Params, page_ids):
     """Scatter a whole prefill's KV into pages ``page_ids`` of every
     layer's pool in one shot — page j of the bucketed prompt (positions
-    [j·page, (j+1)·page)) lands in pool page ``page_ids[j]``.  One pool
-    copy per admission instead of one per page."""
-    page_size = pool["k"].shape[3]
-
-    def write(pl, cl):
-        # cl (G, P, 1, S, KV, hd), S = len(page_ids)·page
-        g, p = cl.shape[0], cl.shape[1]
-        pages = cl[:, :, 0].reshape(
-            g, p, -1, page_size, cl.shape[-2], cl.shape[-1])
-        return pl.at[:, :, page_ids].set(pages)
-
-    return {"k": write(pool["k"], cache["k"]),
-            "v": write(pool["v"], cache["v"])}
+    [j·page, (j+1)·page)) lands in pool page ``page_ids[j]``.  One
+    scatter per admission, in place: the engine donates the pool.  The
+    cache is (G, P, 1, S, KV, hd), S = len(page_ids)·page, so its rows are
+    each layer's pages in order."""
+    return {k: _set_pages(a, page_ids, cache[k]) for k, a in pool.items()}
 
 
 def _pool_copy_page(pool: Params, src, dst):
     """Copy-on-write fork: duplicate page ``src`` into ``dst`` across
     every layer's pool."""
-    return {"k": pool["k"].at[:, :, dst].set(pool["k"][:, :, src]),
-            "v": pool["v"].at[:, :, dst].set(pool["v"][:, :, src])}
+    return {k: _set_pages(a, dst, a[:, :, src]) for k, a in pool.items()}
 
 
 def _pool_gather_pages(pool: Params, page_ids):
@@ -179,8 +185,7 @@ def _pool_gather_pages(pool: Params, page_ids):
 def _pool_scatter_pages(pool: Params, kv: Params, page_ids):
     """Swap-in: write a gathered snapshot back at fresh page ids.  Padding
     entries target the trash page, which is garbage by design."""
-    return {"k": pool["k"].at[:, :, page_ids].set(kv["k"]),
-            "v": pool["v"].at[:, :, page_ids].set(kv["v"])}
+    return {k: _set_pages(a, page_ids, kv[k]) for k, a in pool.items()}
 
 
 def _pool_get_page(pool: Params, page_id):
@@ -192,8 +197,7 @@ def _pool_get_page(pool: Params, page_id):
 def _pool_set_page(pool: Params, kv: Params, page_id):
     """Cache promotion: write one host-restored page's KV back into a
     freshly allocated page of every layer's pool."""
-    return {"k": pool["k"].at[:, :, page_id].set(kv["k"]),
-            "v": pool["v"].at[:, :, page_id].set(kv["v"])}
+    return {k: _set_pages(a, page_id, kv[k]) for k, a in pool.items()}
 
 
 class Engine:
@@ -300,7 +304,7 @@ class Engine:
                 k = self.pool["k"]
                 page_nbytes = 2 * (k.size // k.shape[2]) * k.dtype.itemsize
                 self._page_get = jax.jit(_pool_get_page)
-                self._page_set = jax.jit(_pool_set_page)
+                self._page_set = jax.jit(_pool_set_page, donate_argnums=0)
                 self.prefix_cache = PrefixCache(
                     self.page_pool, page_nbytes,
                     budget_bytes=int(prefix_cache_budget or 0),
@@ -310,18 +314,23 @@ class Engine:
             self.block_tables = np.full(
                 (self.max_slots, self.max_pages), PagePool.TRASH_PAGE,
                 np.int32)
+            # every program that writes the pool donates it (the caller
+            # rebinds the result), so the pool is updated in place
             self._decode = jax.jit(
-                steps_mod.make_paged_decode_step(model, mesh=mesh, plan=plan))
+                steps_mod.make_paged_decode_step(model, mesh=mesh, plan=plan),
+                donate_argnums=1)
             self._prefill = jax.jit(
                 steps_mod.make_prefill_full(model, mesh=mesh, plan=plan))
-            self._page_write = jax.jit(_pool_write_pages)
-            self._copy_page = jax.jit(_pool_copy_page)
+            self._page_write = jax.jit(_pool_write_pages, donate_argnums=0)
+            self._copy_page = jax.jit(_pool_copy_page, donate_argnums=0)
             self._gather_pages = jax.jit(_pool_gather_pages)
-            self._scatter_pages = jax.jit(_pool_scatter_pages)
+            self._scatter_pages = jax.jit(_pool_scatter_pages,
+                                          donate_argnums=0)
             if self.prefill_chunk:
                 self._chunk_prefill = jax.jit(
                     steps_mod.make_chunked_prefill_step(model, mesh=mesh,
-                                                        plan=plan))
+                                                        plan=plan),
+                    donate_argnums=1)
             if self.spec_k:
                 # the draft tier's KV lives in a parallel page pool
                 # addressed by the same block tables / page ids
@@ -329,16 +338,19 @@ class Engine:
                                                         self.page_size)
                 self._draft_decode = jax.jit(
                     steps_mod.make_paged_decode_step(model, mesh=mesh,
-                                                     plan=draft_plan))
+                                                     plan=draft_plan),
+                    donate_argnums=1)
                 self._draft_prefill = jax.jit(
                     steps_mod.make_prefill_full(model, mesh=mesh,
                                                 plan=draft_plan))
                 self._verify = jax.jit(
-                    steps_mod.make_verify_step(model, mesh=mesh, plan=plan))
+                    steps_mod.make_verify_step(model, mesh=mesh, plan=plan),
+                    donate_argnums=1)
                 if self.prefill_chunk:
                     self._draft_chunk_prefill = jax.jit(
                         steps_mod.make_chunked_prefill_step(
-                            model, mesh=mesh, plan=draft_plan))
+                            model, mesh=mesh, plan=draft_plan),
+                        donate_argnums=1)
         else:
             self.cache = model.init_cache(self.max_slots, self.max_len)
             spec = model.cache_spec()
@@ -1191,7 +1203,9 @@ class Engine:
         shapes (target and draft tiers alike), the write-cutoff-gated
         batched decode, COW page copies, swap gathers/scatters, and
         draft/verify windows — so steady-state throughput excludes
-        compile time.  Results are discarded — no engine state changes."""
+        compile time.  Results are discarded and every pool write lands in
+        the trash page, so no engine state changes; the pool-writing
+        programs donate the pool, so each call's returned pool is rebound."""
         with self.tracer.span("warmup", track="engine"):
             return self._warmup_impl()
 
@@ -1201,7 +1215,7 @@ class Engine:
             if self.prefill_chunk:
                 trash_row = jnp.full((1, self.max_pages),
                                      PagePool.TRASH_PAGE, jnp.int32)
-                logits, _ = self._chunk_prefill(
+                logits, self.pool = self._chunk_prefill(
                     self.params, self.pool, trash_row,
                     jnp.zeros((1, self.prefill_chunk), jnp.int32),
                     jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
@@ -1215,29 +1229,28 @@ class Engine:
                         {"tokens": jnp.zeros((1, b), jnp.int32)})
                     trash = np.full(b // self.page_size,
                                     PagePool.TRASH_PAGE, np.int32)
-                    jax.block_until_ready(self._page_write(
-                        self.pool, cache, jnp.asarray(trash))["k"])
+                    self.pool = self._page_write(
+                        self.pool, cache, jnp.asarray(trash))
                     jax.block_until_ready(logits)
             if self.prefix_sharing:
-                jax.block_until_ready(self._copy_page(
+                self.pool = self._copy_page(
                     self.pool, jnp.asarray(0, jnp.int32),
-                    jnp.asarray(0, jnp.int32))["k"])
+                    jnp.asarray(0, jnp.int32))
             if self.prefix_cache is not None:
                 zero = jnp.asarray(PagePool.TRASH_PAGE, jnp.int32)
                 snap = self._page_get(self.pool, zero)
                 jax.block_until_ready(snap["k"])
-                jax.block_until_ready(
-                    self._page_set(self.pool, snap, zero)["k"])
+                self.pool = self._page_set(self.pool, snap, zero)
             if self.preemption:
                 ids = jnp.zeros(self.max_pages, jnp.int32)
                 snap = self._gather_pages(self.pool, ids)
                 jax.block_until_ready(snap["k"])
-                jax.block_until_ready(
-                    self._scatter_pages(self.pool, snap, ids)["k"])
+                self.pool = self._scatter_pages(self.pool, snap, ids)
             out = self._decode(
                 self.params, self.pool, jnp.asarray(self.block_tables),
                 jnp.asarray(self._tok), jnp.asarray(self._pos),
                 jnp.zeros(self.max_slots, jnp.int32))
+            self.pool = out[2]
             jax.block_until_ready(out[0])
             if self.spec_k:
                 if self.prefill_chunk:
@@ -1245,7 +1258,7 @@ class Engine:
                     # shape as the target tier, draft weights
                     trash_row = jnp.full((1, self.max_pages),
                                          PagePool.TRASH_PAGE, jnp.int32)
-                    dlogits, _ = self._draft_chunk_prefill(
+                    dlogits, self.draft_pool = self._draft_chunk_prefill(
                         self.draft_params, self.draft_pool, trash_row,
                         jnp.zeros((1, self.prefill_chunk), jnp.int32),
                         jnp.asarray(0, jnp.int32),
@@ -1259,21 +1272,24 @@ class Engine:
                             {"tokens": jnp.zeros((1, b), jnp.int32)})
                         trash = np.full(b // self.page_size,
                                         PagePool.TRASH_PAGE, np.int32)
-                        jax.block_until_ready(self._page_write(
-                            self.draft_pool, dcache,
-                            jnp.asarray(trash))["k"])
+                        self.draft_pool = self._page_write(
+                            self.draft_pool, dcache, jnp.asarray(trash))
                 out = self._draft_decode(
                     self.draft_params, self.draft_pool,
                     jnp.asarray(self.block_tables), jnp.asarray(self._tok),
                     jnp.asarray(self._pos),
                     jnp.zeros(self.max_slots, jnp.int32))
+                self.draft_pool = out[2]
                 jax.block_until_ready(out[0])
                 out = self._verify(
                     self.params, self.pool, jnp.asarray(self.block_tables),
                     jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32),
                     jnp.asarray(self._pos),
                     jnp.zeros(self.max_slots, jnp.int32))
+                self.pool = out[2]
                 jax.block_until_ready(out[0])
+            jax.block_until_ready(
+                (self.pool, self.draft_pool) if self.spec_k else self.pool)
         else:
             sub = self.model.init_cache(1, self.max_len)
             out = self._decode(self.params, sub,

@@ -125,3 +125,29 @@ def test_decompress_compiles(one_chip, qmode):
     w = _on(one_chip, sod._abstract_tiled((), D_FF, D_MODEL, jnp.bfloat16,
                                           TILE, CAP, qmode=_qmode(qmode)))
     _assert_kernel(lambda w: decompress_pallas(w, interpret=False), w)
+
+
+@pytest.fixture(scope="module")
+def v5e_pool_engine():
+    from test_pool_in_place import pool_engine
+
+    from repro import configs
+
+    # the KV heads and head size tile the chip's (8, 128) vreg as the
+    # published configurations do
+    cfg = configs.reduced(configs.get_config("internlm2-1.8b")).with_(
+        n_heads=8, n_kv_heads=8, head_dim=128)
+    return pool_engine(cfg)
+
+
+@pytest.mark.parametrize("program", [
+    "decode", "page_write", "chunk_prefill", "verify", "copy_page",
+    "scatter_pages", "page_set"])
+def test_pool_programs_update_in_place_on_v5e(one_chip, v5e_pool_engine,
+                                              program):
+    """The engine's pool-writing programs, compiled for the chip with a
+    bf16 pool, alias the donated pool and keep no per-layer copy of it
+    (``tests/test_pool_in_place.py`` makes the check on the CPU)."""
+    from test_pool_in_place import assert_in_place
+
+    assert_in_place(v5e_pool_engine, program, sharding=one_chip)
