@@ -2,17 +2,23 @@
 
 InternLM2 (arXiv:2403.17297) is a pre-norm decoder of the LLaMA design:
 RMSNorm, grouped-query attention with rotary position embeddings (rotate
-half, base ``rope_theta``), a SiLU-gated MLP and an untied output head.  The
-configuration's checkpoint prunes every projection and the head to 30% of
-its weights by magnitude, per matrix, keeping each weight whose |value| is
-at least the k-th largest, k = round(0.3 * size): :func:`checkpoint_matrix`
-makes it from the seed, one matrix at a time.  The system under test is
-handed this checkpoint and packs it; the reference reads it again.
+half, base ``rope_theta``), a SiLU-gated MLP and an untied output head.  Its
+matrices are seeded here (:data:`ROLES`, :func:`matrix_shape`).  The
+configuration's checkpoint prunes every projection and the head to its
+``sparsity.density`` (30%) of weights by magnitude, per matrix, keeping
+each weight whose |value| is at least the k-th largest, k = round(density
+* size); a configuration that states no density, as internlm2-1.8b-dense,
+which loads this module, prunes nothing.  :func:`checkpoint_matrix` makes
+the checkpoint from the seed, one matrix at a time.  The system under test
+is handed this checkpoint and packs it; the reference reads it again.
 
 The reference is written from that description in ``jax.numpy``: float32
 activations and ``highest`` matmul precision throughout, no kernel, cache or
 batching of the program, one layer at a time, so it fits beside nothing
-else on the chip.
+else on the chip.  :func:`first_choices` runs the same decoder a step below
+the configuration's bf16, every weight and every tensor a bf16 program
+stores rounded through float8 e4m3: the control of a configuration whose
+program has no lower-precision path of its own.
 """
 from __future__ import annotations
 
@@ -25,6 +31,34 @@ import numpy as np
 from harness import weights
 
 HEAD_BLOCK = 256          # positions per block of the output projection
+E4M3_MAX = 448.0          # float8 e4m3's largest finite value
+# the order numbers each role's seeded key (weights.role_id): fixed
+ROLES = ("embed", "head", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+         "w_down")
+LAYER_ROLES = ROLES[2:]
+EMBED_STD = 0.02
+
+
+def matrix_shape(cfg: dict, role: str) -> tuple[int, int]:
+    """(fan_in, fan_out) of a role's matrix under ``cfg`` (HF key names)."""
+    d = cfg["hidden_size"]
+    hd = cfg["head_dim"]
+    q = cfg["num_attention_heads"] * hd
+    kv = cfg["num_key_value_heads"] * hd
+    f = cfg["intermediate_size"]
+    v = cfg["vocab_size"]
+    return {"embed": (v, d), "head": (d, v), "wq": (d, q), "wk": (d, kv),
+            "wv": (d, kv), "wo": (q, d), "w_gate": (d, f), "w_up": (d, f),
+            "w_down": (f, d)}[role]
+
+
+def seeded_matrix(cfg: dict, seed: int, role: str, layer: int) -> jax.Array:
+    """One matrix as initialised: linear maps N(0, 1/fan_in), the
+    embedding N(0, 0.02^2), in bf16."""
+    shape = matrix_shape(cfg, role)
+    std = EMBED_STD if role == "embed" else shape[0] ** -0.5
+    return weights.layer_matrix(seed, role, layer, shape=shape, std=std,
+                                numbered=ROLES)
 
 
 def magnitude_prune(w: jax.Array, density: float) -> jax.Array:
@@ -52,15 +86,35 @@ def checkpoint_matrix(cfg: dict, seed: int, role: str,
                       layer: int) -> jax.Array:
     """One matrix of the pruned bf16 checkpoint (the embedding is not
     pruned)."""
-    w = weights.layer_matrix(seed, role, layer, cfg)
-    if role == "embed":
+    w = seeded_matrix(cfg, seed, role, layer)
+    density = cfg["sparsity"].get("density", 1.0)
+    if role == "embed" or density >= 1.0:
         return w
-    return _prune(w, density=cfg["sparsity"]["density"])
+    return _prune(w, density=density)
 
 
-def _layer_weights(cfg: dict, seed: int, layer: int) -> dict:
-    return {r: checkpoint_matrix(cfg, seed, r, layer).astype(jnp.float32)
-            for r in weights.LAYER_ROLES}
+def _fp8(x):
+    """``x`` rounded through float8 e4m3, one scale a tensor (its largest
+    magnitude onto e4m3's largest finite value), back in float32."""
+    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / E4M3_MAX
+    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+
+
+_round = jax.jit(_fp8)
+
+
+def _keep(x):
+    return x
+
+
+def _matrix(cfg: dict, seed: int, role: str, layer: int, fp8: bool):
+    w = checkpoint_matrix(cfg, seed, role, layer).astype(jnp.float32)
+    return _round(w) if fp8 else w
+
+
+def _layer_weights(cfg: dict, seed: int, layer: int,
+                   fp8: bool = False) -> dict:
+    return {r: _matrix(cfg, seed, r, layer, fp8) for r in LAYER_ROLES}
 
 
 def _rms_norm(x, eps):
@@ -78,23 +132,28 @@ def _rope(x, theta):
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "kv_heads", "hd",
-                                             "eps", "theta"))
-def _block(x, w, *, heads, kv_heads, hd, eps, theta):
+                                             "eps", "theta", "fp8"))
+def _block(x, w, *, heads, kv_heads, hd, eps, theta, fp8=False):
+    """One layer.  ``fp8`` rounds what a bf16 program stores (the
+    normed input of each projection, q, k and v, the attention output, the
+    MLP's hidden product and the residual stream) through float8 e4m3."""
+    r = _fp8 if fp8 else _keep
     b, s, _ = x.shape
     with jax.default_matmul_precision("highest"):
-        h = _rms_norm(x, eps)
-        q = _rope((h @ w["wq"]).reshape(b, s, heads, hd), theta)
-        k = _rope((h @ w["wk"]).reshape(b, s, kv_heads, hd), theta)
-        v = (h @ w["wv"]).reshape(b, s, kv_heads, hd)
+        h = r(_rms_norm(x, eps))
+        q = r(_rope((h @ w["wq"]).reshape(b, s, heads, hd), theta))
+        k = r(_rope((h @ w["wk"]).reshape(b, s, kv_heads, hd), theta))
+        v = r((h @ w["wv"]).reshape(b, s, kv_heads, hd))
         g = heads // kv_heads                    # query head i reads kv i // g
         q = q.reshape(b, s, kv_heads, g, hd)
         scores = jnp.einsum("bqkgd,bckd->bkgqc", q, k) * hd ** -0.5
         causal = jnp.tril(jnp.ones((s, s), bool))
         p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-        o = jnp.einsum("bkgqc,bckd->bqkgd", p, v).reshape(b, s, heads * hd)
-        x = x + o @ w["wo"]
-        h = _rms_norm(x, eps)
-        x = x + (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
+        o = r(jnp.einsum("bkgqc,bckd->bqkgd", p, v).reshape(b, s, heads * hd))
+        x = r(x + o @ w["wo"])
+        h = r(_rms_norm(x, eps))
+        x = r(x + r(jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"]))
+              @ w["w_down"])
     return x
 
 
@@ -109,6 +168,21 @@ def _head_gaps(h, w_head, query):
     return jnp.where(query >= 0, jnp.max(logits, -1) - got, 0.0)
 
 
+def _hidden(cfg: dict, seed: int, tokens: np.ndarray, fp8: bool):
+    """The decoder's normed last hidden state of ``tokens`` (B, S)."""
+    eps = cfg["rms_norm_eps"]
+    embed = _matrix(cfg, seed, "embed", 0, fp8)
+    x = embed[jnp.asarray(tokens)]
+    del embed
+    for layer in range(cfg["num_hidden_layers"]):
+        x = _block(x, _layer_weights(cfg, seed, layer, fp8),
+                   heads=cfg["num_attention_heads"],
+                   kv_heads=cfg["num_key_value_heads"], hd=cfg["head_dim"],
+                   eps=eps, theta=float(cfg["rope_theta"]), fp8=fp8)
+    h = _rms_norm(x, eps)
+    return _round(h) if fp8 else h
+
+
 def gaps(cfg: dict, seed: int, tokens: np.ndarray,
          query: np.ndarray) -> np.ndarray:
     """Reference logit gaps.
@@ -119,20 +193,27 @@ def gaps(cfg: dict, seed: int, tokens: np.ndarray,
     position, by how much the reference's logit of that token lies below
     its best logit there: 0 where it is the reference's own first choice.
     """
-    eps = cfg["rms_norm_eps"]
-    embed = checkpoint_matrix(cfg, seed, "embed", 0).astype(jnp.float32)
-    x = embed[jnp.asarray(tokens)]
-    del embed
-    for layer in range(cfg["num_hidden_layers"]):
-        x = _block(x, _layer_weights(cfg, seed, layer),
-                   heads=cfg["num_attention_heads"],
-                   kv_heads=cfg["num_key_value_heads"], hd=cfg["head_dim"],
-                   eps=eps, theta=float(cfg["rope_theta"]))
-    h = _rms_norm(x, eps)
-    w_head = checkpoint_matrix(cfg, seed, "head", 0).astype(jnp.float32)
+    h = _hidden(cfg, seed, tokens, fp8=False)
+    w_head = _matrix(cfg, seed, "head", 0, fp8=False)
     out = np.zeros(query.shape, np.float32)
     for i in range(0, h.shape[1], HEAD_BLOCK):
         out[:, i:i + HEAD_BLOCK] = np.asarray(_head_gaps(
             h[:, i:i + HEAD_BLOCK], w_head,
             jnp.asarray(query[:, i:i + HEAD_BLOCK])))
     return out
+
+
+@jax.jit
+def _head_first(h, w_head):
+    with jax.default_matmul_precision("highest"):
+        return jnp.argmax(h @ w_head, -1).astype(jnp.int32)
+
+
+def first_choices(cfg: dict, seed: int, tokens: np.ndarray) -> np.ndarray:
+    """The token the decoder computed in float8 e4m3 puts first at every
+    position of ``tokens`` (B, S): the control's query."""
+    h = _hidden(cfg, seed, tokens, fp8=True)
+    w_head = _matrix(cfg, seed, "head", 0, fp8=True)
+    return np.concatenate([np.asarray(_head_first(h[:, i:i + HEAD_BLOCK],
+                                                  w_head))
+                           for i in range(0, h.shape[1], HEAD_BLOCK)], 1)

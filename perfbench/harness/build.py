@@ -1,10 +1,26 @@
-"""The system under test, built from a configuration file and a seed.
+"""The system under test, built from a configuration file, its program
+adapter and a seed.
 
-This module is the one place that knows the program's interfaces: its
-``ModelConfig`` and ``SoDConfig``, the layout of its parameter tree, its
-packer ``core.sod.sodify_params`` and its serving ``Engine``.  The weights
-are the configuration's pruned checkpoint, made on the device from the seed
-by the configuration's own module, as a deployment loads a pruned
+What holds for every architecture lives here: the leaf-by-leaf make, pack
+and drop loop, the KV pool's size, the engine and its prefill buckets, and
+the list of a forward pass's matmuls read from the run's arrays.  What the
+program is for one architecture lives in its adapter,
+``adapters/<model_type>.py``, found by the configuration's ``model_type``
+(``spec.Cell.adapter``).  An adapter is a module with
+
+* ``model_config(cfg, qmode=None)``: the program's ``ModelConfig``;
+* ``leaf(keys, sds, cfg, checkpoint)``: the parameter leaf at path
+  ``keys`` (of the shape and dtype of ``sds``), assembled from
+  ``checkpoint(role, layer, *index)`` matrices;
+* ``matmul(keys, cfg)``: the share of a step's rows that each matrix of
+  the leaf multiplies (1 for a dense or shared matrix, less for a routed
+  expert), or None for a leaf that is no matmul weight.  Matmul weights
+  are handed to the packer;
+* ``kv_page_bytes(mc, page_size)``: bytes of one KV page over all layers;
+* ``attention(cfg)``: each layer's ``workcount.Attention``.
+
+The weights are the configuration's checkpoint, made on the device from
+the seed by the configuration's own module, as a deployment loads a pruned
 checkpoint: each leaf is made and handed to the program's packer, and the
 unpacked copy is dropped before the next leaf is made, so unpacked and
 packed weights are never all resident at once.
@@ -16,34 +32,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from harness import weights
 from harness.workcount import Matrix
-
-# the program's RMSNorm multiplies by (1 + g): a published weight of 1
-_NORMS = ("norm1", "norm2", "final_norm")
-
-
-def model_config(cfg: dict, qmode: str | None = None):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    from repro.core.sod import SoDConfig
-
-    sp = cfg["sparsity"]
-    sod = SoDConfig(mode=sp["format"], density=sp["density"],
-                    prune_method=sp["prune_method"], tile=tuple(sp["tile"]),
-                    br=sp.get("block_rows", 8),
-                    qmode=qmode or sp.get("qmode", "none"))
-    if cfg["tie_word_embeddings"] or cfg["hidden_act"] != "silu":
-        raise ValueError("only untied SiLU-GLU decoders are built here")
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=False, act="silu", dtype=cfg["torch_dtype"], sod=sod)
 
 
 def _nest(keys: list, leaf) -> dict:
@@ -59,11 +50,11 @@ def _dig(tree, keys: list):
     return tree
 
 
-def build_params(model, cfg: dict, checkpoint):
-    """The checkpoint in the program's tree, projections packed by
-    ``sodify_params``.  ``checkpoint(role, layer)`` gives one matrix.
-    Returns ``(params, pack_s)``: ``pack_s`` is the host time the program
-    spent packing, each leaf ending in ``block_until_ready``."""
+def build_params(adapter, model, cfg: dict, checkpoint):
+    """The checkpoint in the program's tree, matmul weights packed by
+    ``sodify_params``.  Returns ``(params, pack_s)``: ``pack_s`` is the
+    host time the program spent packing, each leaf ending in
+    ``block_until_ready``."""
     from repro.core.sod import sodify_params
 
     mc = model.cfg
@@ -75,22 +66,12 @@ def build_params(model, cfg: dict, checkpoint):
     pack_s = 0.0
     for path, sds in flat:
         keys = [p.key for p in path]
-        role = keys[-1]
-        if role in _NORMS:
-            leaves.append(jnp.zeros(sds.shape, sds.dtype))
-            continue
-        if role in ("embed", "head"):
-            leaf = checkpoint(role, 0)
-        elif role in weights.LAYER_ROLES:
-            leaf = jnp.stack([checkpoint(role, i) for i in range(
-                cfg["num_hidden_layers"])]).reshape(sds.shape)
-        else:
-            raise ValueError(f"no checkpoint matrix for leaf {keys}")
+        leaf = adapter.leaf(keys, sds, cfg, checkpoint)
         if leaf.shape != sds.shape or leaf.dtype != sds.dtype:
             raise ValueError(f"{keys}: made {leaf.shape} {leaf.dtype}, the "
                              f"program wants {sds.shape} {sds.dtype}")
         jax.block_until_ready(leaf)
-        if role != "embed":
+        if adapter.matmul(keys, cfg) is not None:
             t0 = time.perf_counter()
             leaf = _dig(sodify_params(_nest(keys, leaf), mc.sod,
                                       prune=False), keys)
@@ -100,27 +81,21 @@ def build_params(model, cfg: dict, checkpoint):
     return jax.tree_util.tree_unflatten(treedef, leaves), pack_s
 
 
-def kv_page_bytes(mc, page_size: int) -> int:
-    """Bytes of one KV page over all layers (keys and values)."""
-    return (mc.n_layers * 2 * page_size * mc.n_kv_heads * mc.head_dim
-            * jnp.dtype(mc.dtype).itemsize)
-
-
 # Device memory a step needs beside the weights and two pools: a layer's
 # gather of every slot's pages, and a prefill's logits (bucket x vocabulary
 # x 4 bytes) and its contiguous KV before it is written into pages.
 STEP_HEADROOM_BYTES = 2 * 2**30
 
 
-def pool_pages(mc, traffic: dict, max_len: int, free_bytes: int) -> int:
-    """Pages of the engine's KV pool: every slot's worst case where that
-    fits, otherwise the largest pool that fits twice in the memory left
-    after the weights and a step's headroom (a multiple of 16, plus the
-    trash page).  Twice, because the engine's decode and prefill programs
-    return a new pool without donating the old one."""
-    page = traffic["page_size"]
-    worst = 1 + traffic["slots"] * math.ceil(max_len / page)
-    fit = (free_bytes - STEP_HEADROOM_BYTES) // (2 * kv_page_bytes(mc, page))
+def pool_pages(page_bytes: int, traffic: dict, max_len: int,
+               free_bytes: int) -> int:
+    """Pages of the engine's KV pool, of ``page_bytes`` each: every slot's
+    worst case where that fits, otherwise the largest pool that fits twice
+    in the memory left after the weights and a step's headroom (a multiple
+    of 16, plus the trash page).  Twice, because the engine's decode and
+    prefill programs return a new pool without donating the old one."""
+    worst = 1 + traffic["slots"] * math.ceil(max_len / traffic["page_size"])
+    fit = (free_bytes - STEP_HEADROOM_BYTES) // (2 * page_bytes)
     if fit < 17:
         raise ValueError(f"{free_bytes} bytes free hold no pool of 16 pages")
     return min(worst, 1 + (fit - 1) // 16 * 16)
@@ -141,27 +116,34 @@ def bucket(mc, traffic: dict, plen: int) -> int:
     return bucket_len(plen, traffic["page_size"], mc.attn_chunk)
 
 
-def packed_matrices(params) -> list[Matrix]:
-    """Every packed matmul of one forward pass, read from the run's packed
-    arrays: one entry per layer and matrix, and one for the head."""
+def matmuls(params, adapter, cfg: dict) -> list[Matrix]:
+    """Every matmul of one forward pass, read from the run's arrays: one
+    entry per matrix (a leaf stacks them by layer, and by expert where it
+    has experts), tagged with what computes it: the program's kernel for a
+    packed operand (TiledCSC: ``pallas_fused``, BlockCSR:
+    ``pallas_block``), an XLA dot for a dense one."""
     from repro.core.formats import BlockCSR, TiledCSC
 
     out = []
     flat = jax.tree_util.tree_flatten_with_path(
         params, is_leaf=lambda x: isinstance(x, (TiledCSC, BlockCSR)))[0]
     for path, leaf in flat:
-        if not isinstance(leaf, (TiledCSC, BlockCSR)):
+        keys = [p.key for p in path]
+        share = adapter.matmul(keys, cfg)
+        if share is None:
             continue
-        role = path[-1].key
-        k, n = leaf.shape
+        k, n = leaf.shape[-2:]
         if isinstance(leaf, TiledCSC):
-            vals, valid = leaf.vals, leaf.rows >= 0
+            kernel, vals, valid = "pallas_fused", leaf.vals, leaf.rows >= 0
             per = jnp.sum(valid.reshape(-1, *valid.shape[-4:]), (1, 2, 3, 4))
-        else:
-            vals = leaf.block_vals
+        elif isinstance(leaf, BlockCSR):
+            kernel, vals = "pallas_block", leaf.block_vals
             per = jnp.sum((vals != 0).reshape(-1, *vals.shape[-5:]),
                           (1, 2, 3, 4, 5))
+        else:
+            kernel, vals = "xla_dot", leaf
+            per = np.full(math.prod(leaf.shape[:-2]), k * n, np.int64)
         for nnz in jax.device_get(per).tolist():
-            out.append(Matrix(role, int(k), int(n), int(nnz),
-                              vals.dtype.itemsize))
+            out.append(Matrix(keys[-1], int(k), int(n), int(nnz),
+                              vals.dtype.itemsize, kernel, share))
     return out

@@ -1,11 +1,13 @@
 """A cell of ``BENCHMARK.json`` and the files it names.
 
-Everything that belongs to one configuration, traffic mix or metric is a
-file of its own, found by name: ``configs/<config>.json`` (sizes) with its
-reference ``configs/<config>.py``, ``traffic/<traffic>.json``,
-``metrics/<metric>.py`` and ``limits/<cell>.json`` (the correctness limits
-of one cell).  Adding a cell, a mix, a configuration or a metric adds files
-and entries; no file here changes.
+Everything that belongs to one configuration, traffic mix, metric or
+architecture is a file of its own, found by name: ``configs/<config>.json``
+(sizes) with its reference ``configs/<config>.py``, the program's adapter
+for the configuration's ``model_type``, ``adapters/<model_type>.py``,
+``traffic/<traffic>.json``, ``metrics/<metric>.py`` and
+``limits/<cell>.json`` (the correctness limits of one cell).  Adding a
+cell, a mix, a configuration, an architecture or a metric adds files and
+entries; no file here changes.
 """
 from __future__ import annotations
 
@@ -32,6 +34,10 @@ class Cell:
     @property
     def reference(self) -> pathlib.Path:
         return self.base / "configs" / f"{self.config_name}.py"
+
+    @property
+    def adapter(self) -> pathlib.Path:
+        return self.base / "adapters" / f"{self.config['model_type']}.py"
 
     def metric_reader(self, name: str) -> pathlib.Path:
         return self.base / "metrics" / f"{name}.py"
@@ -61,5 +67,10 @@ def load(bench_path: pathlib.Path, root: pathlib.Path, workload: str,
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
-    return Cell(workload, w["config"], w["traffic"], w["chips"], config, mix,
-                e2e, per_layer, limits["limits"], base)
+    cell = Cell(workload, w["config"], w["traffic"], w["chips"], config,
+                mix, e2e, per_layer, limits["limits"], base)
+    if not cell.adapter.is_file():
+        known = sorted(p.stem for p in cell.adapter.parent.glob("*.py"))
+        raise SystemExit(f"no adapter for model_type "
+                         f"{config['model_type']!r}; adapters: {known}")
+    return cell

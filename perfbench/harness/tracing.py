@@ -6,7 +6,8 @@ steps, when the device is idle.  The harness marks each step on the host
 with a ``TraceAnnotation`` so that idle gaps on the device can be told
 apart by what the host was doing.  :class:`Context` holds everything a
 per-layer metric reads: the trace's reduction, the engine steps that ran
-inside it, the packed matrices and the chip's peaks.
+inside it, the forward pass's matmuls and attention, and the chip's
+peaks.
 """
 from __future__ import annotations
 
@@ -65,13 +66,13 @@ class Context:
     """What per-layer metric readers see (``metrics/<name>.py``)."""
 
     def __init__(self, *, trace, t_start, t_stop, driver, readings, mats,
-                 peaks, cfg, mix, pack_s, bucket):
+                 attention, peaks, mix, pack_s, bucket):
         self.trace = trace
         self.driver = driver
         self.readings = readings
         self.mats = mats
+        self.attention = attention
         self.peaks = peaks
-        self.cfg = cfg
         self.mix = mix
         self.pack_s = pack_s
         self.bucket = bucket
@@ -98,18 +99,19 @@ class Context:
 
     def roofline(self, kernel: str) -> float | None:
         """Share (%) of the kernel's device time that the least time of
-        the calls made needs; None when the trace has no such kernel or
-        its calls do not match the programs that ran."""
+        its own matmuls in the calls made needs; None when the trace has
+        no such kernel or its calls do not match the programs that ran."""
         n, s = self.trace.kernels.get(kernel, (0, 0.0))
+        mats = [m for m in self.mats if m.kernel == kernel]
         calls = self.program_calls()
-        if not n or not calls:
+        if not n or not calls or not mats:
             return None
-        if n != len(calls) * len(self.mats):
+        if n != len(calls) * len(mats):
             print(f"roofline {kernel}: {n} kernel events in the trace, "
-                  f"{len(calls)} programs x {len(self.mats)} packed "
+                  f"{len(calls)} programs x {len(mats)} {kernel} "
                   f"matmuls expected", file=sys.stderr)
             return None
-        least = sum(workcount.program_least_time_s(m, self.mats, self.peaks)
+        least = sum(workcount.program_least_time_s(m, mats, self.peaks)
                     for _, m in calls)
         return 100.0 * least / s
 
@@ -119,8 +121,7 @@ class Context:
         a share (%) of the chip's bf16 peak."""
         t0 = self.driver.t0
         t1 = t0 + self.readings.window_s
-        w = workcount.token_ops(self.mats, 0, self.cfg)     # weights only
-        a = workcount.token_ops([], 1, self.cfg)            # per position
+        w = workcount.weight_ops(self.mats)
         ops = 0.0
         for s in self.driver.served.values():
             plen = len(s.req.prompt)
@@ -128,9 +129,11 @@ class Context:
                 if not t0 < t <= t1:
                     continue
                 if i == 0:        # the prompt: positions 1..plen attended
-                    ops += plen * w + a * plen * (plen + 1) / 2
+                    ops += plen * w + workcount.prompt_attention_ops(
+                        self.attention, plen)
                 else:
-                    ops += w + a * (plen + i)
+                    ops += w + workcount.attention_ops(self.attention,
+                                                       plen + i)
         if not ops:
             return None
         return 100.0 * ops / self.readings.window_s / self.peaks["bf16_flops"]

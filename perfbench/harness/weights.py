@@ -1,27 +1,28 @@
-"""Seeded weights of a configuration, in the published parameterisation.
+"""Seeded weights, in the published parameterisation.
 
 Part of the yardstick: the harness feeds these values to the system under
 test, and the reference regenerates them here, one layer at a time, to
-check what the system served.  Nothing here imports the program.
+check what the system served.  Nothing here imports the program, and
+nothing here knows a model: a configuration's module names its matrices
+and gives their shapes and scales.
 
-Every matrix of layer ``l`` with role ``r`` is drawn from its own key,
-``fold_in(fold_in(seed_key, ROLES.index(r)), l)``, so one layer's matrices
-can be made without the others.  Values follow the usual random
-initialisation: linear maps N(0, 1/fan_in), the embedding N(0, 0.02^2),
-RMSNorm weights 1.  They are drawn in float32 and stored in the served
+Every matrix is drawn from its own key: the seed's, folded with the
+matrix's role, then its layer, then any further index (an expert, say), so
+one matrix can be made without the others.  A role's number is its place
+in the configuration module's ``numbered`` roles where it is listed there,
+so ``fold_in(fold_in(seed_key, numbered.index(role)), layer)``, and
+otherwise a stable hash of its name, which adding another role cannot
+change.  Values follow the usual random initialisation, drawn in float32
+with the standard deviation the module gives and stored in the served
 dtype.
 """
 from __future__ import annotations
 
 import functools
+import zlib
 
 import jax
 import jax.numpy as jnp
-
-ROLES = ("embed", "head", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-         "w_down")
-LAYER_ROLES = ROLES[2:]
-EMBED_STD = 0.02
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -33,25 +34,13 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def role_key(seed: int, role: str) -> jax.Array:
-    return jax.random.fold_in(seed_key(seed), ROLES.index(role))
-
-
-def matrix_shape(cfg: dict, role: str) -> tuple[int, int]:
-    """(fan_in, fan_out) of a role's matrix under ``cfg`` (HF key names)."""
-    d = cfg["hidden_size"]
-    hd = cfg["head_dim"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    f = cfg["intermediate_size"]
-    v = cfg["vocab_size"]
-    return {"embed": (v, d), "head": (d, v), "wq": (d, q), "wk": (d, kv),
-            "wv": (d, kv), "wo": (q, d), "w_gate": (d, f), "w_up": (d, f),
-            "w_down": (f, d)}[role]
-
-
-def _std(role: str, fan_in: int) -> float:
-    return EMBED_STD if role == "embed" else fan_in ** -0.5
+def role_id(role: str, numbered: tuple[str, ...] = ()) -> int:
+    """The number a role's key is folded with: its place in ``numbered``,
+    else the CRC-32 of its name with the top bit set, so that it never
+    meets a place."""
+    if role in numbered:
+        return numbered.index(role)
+    return zlib.crc32(role.encode()) | 0x80000000
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "std", "dtype"))
@@ -59,10 +48,15 @@ def _normal(key, *, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def layer_matrix(seed: int, role: str, layer: int, cfg: dict,
+def layer_matrix(seed: int, role: str, layer: int, *index: int,
+                 shape: tuple[int, ...], std: float,
+                 numbered: tuple[str, ...] = (),
                  dtype=jnp.bfloat16) -> jax.Array:
-    """One layer's matrix of ``role`` (layer 0 for embed and head)."""
-    shape = matrix_shape(cfg, role)
-    key = jax.random.fold_in(role_key(seed, role), layer)
-    return _normal(key, shape=shape, std=_std(role, shape[0]),
+    """The matrix of ``role`` in ``layer`` (0 for one outside the layers)
+    at further ``index``, N(0, std^2) of ``shape``."""
+    key = jax.random.fold_in(
+        jax.random.fold_in(seed_key(seed), role_id(role, numbered)), layer)
+    for i in index:
+        key = jax.random.fold_in(key, i)
+    return _normal(key, shape=tuple(shape), std=float(std),
                    dtype=jnp.dtype(dtype))

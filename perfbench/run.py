@@ -6,9 +6,11 @@
 
 The cell, its configuration and its traffic mix are looked up by name in
 ``BENCHMARK.json`` and in the files under ``perfbench/``.  The run makes the
-weights on the device from ``--seed``, prunes and packs them with the
-program's packer, builds the serving engine, warms every shape the mix can
-use, and drives the engine for ``--seconds`` from its own loop.  With
+weights on the device from ``--seed``, packs them with the program's
+packer where the configuration states a packed format, builds the serving
+engine through the adapter its ``model_type`` names
+(``adapters/<model_type>.py``), warms every shape the mix can use, and
+drives the engine for ``--seconds`` from its own loop.  With
 ``--trace 1`` it also records a profiler trace of a steady part of the
 window and reports the cell's per-layer metrics instead of its end-to-end
 ones.  After the window it frees the engine and checks a sample of the
@@ -100,13 +102,16 @@ class Run:
         self.cell = cell
         self.compiles = _CompileCounter()
         cfg, mix = cell.config, cell.mix
-        self.mc = mc = build.model_config(cfg)
+        self.adapter = adapter = _load_module(cell.adapter,
+                                              "perfbench_adapter")
+        self.mc = mc = adapter.model_config(cfg)
         model = LM(mc)
         self.ref = _load_module(cell.reference, "perfbench_reference")
         params, self.pack_s = build.build_params(
-            model, cfg, lambda role, layer: self.ref.checkpoint_matrix(
-                cfg, seed, role, layer))
-        self.mats = build.packed_matrices(params)
+            adapter, model, cfg,
+            lambda role, layer, *index: self.ref.checkpoint_matrix(
+                cfg, seed, role, layer, *index))
+        self.mats = build.matmuls(params, adapter, cfg)
         gen = traffic.Generator(mix, seed, cfg["vocab_size"])
         plens, outs = gen.lengths()
         lo, hi = int(plens.min()), int(plens.max())
@@ -115,7 +120,9 @@ class Run:
             # a steady-state request's prompt holds what it served so far
             hi += int(outs.max()) - 1
             self.max_len = max(self.max_len, build.bucket(mc, mix, hi))
-        n_pages = build.pool_pages(mc, mix, self.max_len, device.free_bytes())
+        n_pages = build.pool_pages(
+            adapter.kv_page_bytes(mc, mix["page_size"]), mix, self.max_len,
+            device.free_bytes())
         eng = build.engine(model, params, mix, self.max_len, n_pages)
         del params
         self.driver = driver = drive.Driver(eng, gen, mix)
@@ -169,7 +176,8 @@ class Run:
         mc, mix = self.mc, self.cell.mix
         return self.tracer.context(
             driver=self.driver, readings=self.readings, mats=self.mats,
-            peaks=peaks, cfg=self.cell.config, mix=mix,
+            attention=self.adapter.attention(self.cell.config), peaks=peaks,
+            mix=mix,
             pack_s=self.pack_s, bucket=lambda p: build.bucket(mc, mix, p))
 
     def sample_and_free(self, seed: int):

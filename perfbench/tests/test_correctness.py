@@ -1,10 +1,12 @@
 """``correct`` comes out true on a sound run and false on a broken one.
 
 Runs the whole of ``run.py`` but its look for a chip, on the CPU at a tiny
-size (two layers of width 256), with the timed path broken underneath: the
-decode step that returns the KV pool unchanged, half of the batch left
-undecoded, and every produced token altered.  The control, the program's
-own fp8 value path, has to fail the same limits that sound runs pass.
+size (two layers of width 256), in every cell, the dense one too, with the
+timed path broken underneath: the decode step that returns the KV pool
+unchanged, half of the batch left undecoded, and every produced token
+altered.  A cell's control has to fail the same limits that sound runs
+pass: the program's own fp8 value path for the packed configuration, the
+reference decoder computed in fp8 e4m3 for the dense one.
 
     JAX_PLATFORMS=cpu python -m pytest -q perfbench/tests
 """
@@ -24,9 +26,12 @@ import run  # noqa: E402
 from harness import checks, spec  # noqa: E402
 
 CELLS = ("internlm2-1.8b-tcsc30.decode-batch",
-         "internlm2-1.8b-tcsc30.chat-rate")
+         "internlm2-1.8b-tcsc30.chat-rate",
+         "internlm2-1.8b-dense.decode-batch")
 SECONDS = "4"
-CONTROL = "fp8"         # the cell's control: values one step below bf16
+# each configuration's control: its weights one step below bf16
+CONTROLS = {"internlm2-1.8b-tcsc30.decode-batch": "fp8",
+            "internlm2-1.8b-dense.decode-batch": "fp8_reference"}
 
 
 @pytest.fixture(autouse=True)
@@ -96,13 +101,15 @@ def test_broken_timed_path_is_incorrect(tmp_path, capsys, monkeypatch, cell,
     assert line["correct"] is False, line["checks"]
 
 
-def test_control_fails_where_the_program_passes(tmp_path, capsys):
+@pytest.mark.parametrize("cell_name", sorted(CONTROLS))
+def test_control_fails_where_the_program_passes(tmp_path, capsys,
+                                                cell_name):
     """The control on three seeds reads above a limit that the program's
     own runs keep."""
     import control
 
     root = tiny.make(tmp_path / "root")
-    cell = spec.load(root / "BENCHMARK.json", root, CELLS[0],
+    cell = spec.load(root / "BENCHMARK.json", root, cell_name,
                      root / "perfbench")
     ref = run._load_module(cell.reference, "perfbench_reference")
     for seed in (21, 22, 23):
@@ -112,7 +119,7 @@ def test_control_fails_where_the_program_passes(tmp_path, capsys):
         _, prog_ok, _ = checks.compare(ref, cell.config, seed, sample,
                                        cell.limits, served.seq_len)
         cq = control.control_query(cell, seed, sample, tokens,
-                                   served.seq_len, CONTROL)
+                                   served.seq_len, CONTROLS[cell_name])
         ctrl, ctrl_ok, _ = checks.compare(ref, cell.config, seed, sample,
                                           cell.limits, served.seq_len, cq)
         assert prog_ok

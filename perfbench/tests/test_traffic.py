@@ -16,6 +16,7 @@ import tiny
 
 sys.path[:0] = [str(tiny.BENCH), str(tiny.BENCH.parent / "src")]
 
+import run  # noqa: E402
 from harness import build, traffic  # noqa: E402
 
 MIXES = {name: json.loads((tiny.BENCH / "traffic" / f"{name}.json")
@@ -83,14 +84,16 @@ class _Model:
 
 
 def test_pool_fits_twice_beside_the_headroom():
-    page = build.kv_page_bytes(_Model, 128)
+    adapter = run._load_module(tiny.BENCH / "adapters" / "internlm2.py",
+                               "internlm2_adapter")
+    page = adapter.kv_page_bytes(_Model, 128)
     assert page == 24 * 2 * 128 * 8 * 128 * 2
     mix = {"page_size": 128, "slots": 64}
     free = 13 * 10**9
-    n = build.pool_pages(_Model, mix, 1536, free)
+    n = build.pool_pages(page, mix, 1536, free)
     assert (n - 1) % 16 == 0
     assert 2 * (n - 1) * page <= free - build.STEP_HEADROOM_BYTES
     assert 2 * (n - 1 + 16) * page > free - build.STEP_HEADROOM_BYTES
     # where every slot's worst case fits, that is the pool
-    assert build.pool_pages(_Model, {"page_size": 128, "slots": 2}, 1536,
+    assert build.pool_pages(page, {"page_size": 128, "slots": 2}, 1536,
                             free) == 1 + 2 * 12

@@ -1,5 +1,5 @@
 """Work counts against hand-computed numbers for one layer of the
-configuration, and against the packed arrays of a tiny run."""
+configuration, and against the packed and dense arrays of a tiny run."""
 from __future__ import annotations
 
 import json
@@ -15,6 +15,8 @@ import run  # noqa: E402
 from harness import device, workcount  # noqa: E402
 
 V5E = device.PEAKS["TPU v5 lite"]
+ADAPTER = run._load_module(tiny.BENCH / "adapters" / "internlm2.py",
+                           "internlm2_adapter")
 
 
 def _layer(cfg):
@@ -23,7 +25,7 @@ def _layer(cfg):
     kv = cfg["num_key_value_heads"] * cfg["head_dim"]
     shapes = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
               "w_gate": (d, f), "w_up": (d, f), "w_down": (f, d)}
-    return [workcount.Matrix(r, k, n, round(0.3 * k * n), 2)
+    return [workcount.Matrix(r, k, n, round(0.3 * k * n), 2, "pallas_fused")
             for r, (k, n) in shapes.items()]
 
 
@@ -47,25 +49,28 @@ def test_internlm2_layer_by_hand():
     # one token at context 1000: weights twice, attention 4*ctx*H*hd per
     # layer (one layer here)
     one = dict(cfg, num_hidden_layers=1)
-    assert workcount.token_ops(mats, 1000, one) == 2 * nnz + \
-        4 * 1000 * 16 * 128
+    assert workcount.weight_ops(mats) + workcount.attention_ops(
+        ADAPTER.attention(one), 1000) == 2 * nnz + 4 * 1000 * 16 * 128
+
+
+def _tiny_params(config, seed=7):
+    from harness import build
+    from repro.models.model import LM
+
+    cfg = json.loads((tiny.BENCH / "configs" / f"{config}.json").read_text())
+    cfg.update(tiny.SIZES)
+    model = LM(ADAPTER.model_config(cfg))
+    ref = run._load_module(tiny.BENCH / "configs" / f"{config}.py", "r")
+    params, _ = build.build_params(
+        ADAPTER, model, cfg,
+        lambda role, layer: ref.checkpoint_matrix(cfg, seed, role, layer))
+    return params, build.matmuls(params, ADAPTER, cfg), cfg
 
 
 def test_packed_matrices_count_what_is_stored():
     import jax
 
-    from harness import build
-    from repro.models.model import LM
-
-    cfg = json.loads((tiny.BENCH / "configs" / f"{tiny.CONFIG}.json")
-                     .read_text())
-    cfg.update(tiny.SIZES)
-    model = LM(build.model_config(cfg))
-    ref = run._load_module(tiny.BENCH / "configs" / f"{tiny.CONFIG}.py", "r")
-    params, _ = build.build_params(
-        model, cfg, lambda role, layer: ref.checkpoint_matrix(cfg, 7, role,
-                                                              layer))
-    mats = build.packed_matrices(params)
+    params, mats, cfg = _tiny_params(tiny.CONFIG)
     # 7 projections in each of 2 layers, and the head
     assert len(mats) == 7 * 2 + 1
     want = {m.role: m.nnz for m in _layer(cfg)}
@@ -75,6 +80,22 @@ def test_packed_matrices_count_what_is_stored():
         # k-th largest: bf16 magnitudes tie often (8 bits of mantissa)
         assert want[m.role] <= m.nnz <= want[m.role] * 1.03, m
         assert m.value_bytes == 2
+        assert (m.kernel, m.share) == ("pallas_fused", 1.0)
     dense = params["blocks"]["mlp"]["w_up"].to_dense()
     assert int(jax.numpy.count_nonzero(dense[0, 0])) == next(
         m.nnz for m in mats if m.role == "w_up")
+
+
+def test_dense_matmuls_count_every_value():
+    """The dense configuration's matmuls are the same 15, XLA dots of
+    every stored value: mfu counts them, no kernel's roofline does."""
+    params, mats, cfg = _tiny_params(tiny.DENSE)
+    assert len(mats) == 7 * 2 + 1
+    assert {m.kernel for m in mats} == {"xla_dot"}
+    for m in mats:
+        assert m.nnz == m.k * m.n and m.value_bytes == 2, m
+    assert workcount.weight_ops(mats) == 2 * (
+        2 * sum(k * n for k, n in ((256, 256), (256, 128), (256, 128),
+                                   (256, 256), (256, 512), (256, 512),
+                                   (512, 256))) + 256 * 512)
+    assert params["blocks"]["mlp"]["w_up"].dtype == "bfloat16"

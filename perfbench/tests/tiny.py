@@ -1,5 +1,6 @@
-"""A tiny copy of the benchmark for CPU tests: same harness, same reference
-code, same metric readers; a two-layer model of width 256 and short mixes."""
+"""A tiny copy of the benchmark for CPU tests: same harness, adapters,
+reference code and metric readers; every configuration of the benchmark as
+a two-layer model of width 256, and short mixes."""
 from __future__ import annotations
 
 import json
@@ -8,6 +9,7 @@ import shutil
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 CONFIG = "internlm2-1.8b-tcsc30"
+DENSE = "internlm2-1.8b-dense"
 SIZES = {"hidden_size": 256, "intermediate_size": 512,
          "num_attention_heads": 2, "num_key_value_heads": 1,
          "num_hidden_layers": 2, "vocab_size": 512}
@@ -33,12 +35,15 @@ def make(tmp: pathlib.Path, limits: dict = LIMITS) -> pathlib.Path:
     base = tmp / "perfbench"
     for d in ("configs", "traffic", "limits"):
         (base / d).mkdir(parents=True, exist_ok=True)
-    (base / "metrics").symlink_to(BENCH / "metrics")
-    cfg = json.loads((BENCH / "configs" / f"{CONFIG}.json").read_text())
-    cfg.update(SIZES)
-    (base / "configs" / f"{CONFIG}.json").write_text(json.dumps(cfg))
-    shutil.copy(BENCH / "configs" / f"{CONFIG}.py", base / "configs")
+    for d in ("metrics", "adapters"):
+        (base / d).symlink_to(BENCH / d)
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    for c in bench["configs"]:
+        cfg = json.loads((BENCH / "configs" / f"{c['name']}.json")
+                         .read_text())
+        cfg.update(SIZES)
+        (base / "configs" / f"{c['name']}.json").write_text(json.dumps(cfg))
+        shutil.copy(BENCH / "configs" / f"{c['name']}.py", base / "configs")
     for w in bench["workloads"]:
         mix = json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
                          .read_text())
