@@ -23,6 +23,7 @@ _MODULES = {
     "musicgen-medium": "musicgen_medium",
     "zamba2-2.7b": "zamba2_2_7b",
     "xlstm-125m": "xlstm_125m",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_NAMES = tuple(_MODULES)
@@ -62,6 +63,11 @@ def reduced(cfg: ModelConfig, seq_hint: int = 128) -> ModelConfig:
     if cfg.family == "moe":
         kw.update(n_experts=8, top_k=min(cfg.top_k, 2), ep_axis=4,
                   d_shared_ff=128 if cfg.d_shared_ff else 0)
+        if cfg.experts_held:
+            kw.update(experts_held=(0, 1, 2, 3))
+    if cfg.latent_attention:
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+                  v_head_dim=16, d_ff_dense=256 if cfg.d_ff_dense else 0)
     if cfg.family == "vlm":
         kw.update(frontend_dim=64, n_patches=16)
     if cfg.family == "hybrid":

@@ -44,6 +44,20 @@ class ModelConfig:
     moe_dispatch_blocks: int = 1          # = dp shards for local dispatch
     moe_a2a_axis: str | None = None       # EP axis for shard_map all-to-all
     #                                       dispatch (None = GSPMD scatter)
+    # Held experts (expert parallelism, one chip's share): the ids of the
+    # n_experts this chip holds, in the latent assembly's MoE layers (the
+    # dropless layer of moe.held_moe: the router spans all n_experts, only
+    # these experts' part of the result is computed).
+    experts_held: tuple[int, ...] = ()
+    first_dense_layers: int = 0           # leading layers with a dense MLP
+    d_ff_dense: int = 0                   # their MLP width
+
+    # multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Any = None                      # layers.Yarn rope scaling, or None
 
     # SSM / hybrid (zamba2)
     ssm_state: int = 0
@@ -82,6 +96,11 @@ class ModelConfig:
         the logits matmul — EXPERIMENTS.md §Perf C1).  Logits at padded ids
         are masked to -inf; the logical ``vocab`` is unchanged."""
         return (self.vocab + 127) // 128 * 128
+
+    @property
+    def latent_attention(self) -> bool:
+        """Multi-head latent attention, served over a latent KV page."""
+        return self.kv_lora_rank > 0
 
     @property
     def pattern_period(self) -> int:

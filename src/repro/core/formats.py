@@ -47,6 +47,7 @@ dequantized weight.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -147,12 +148,17 @@ def observed_tiled_cap(w, tile: tuple[int, int]) -> int:
     w = jnp.asarray(w)
     if not w.size:
         return 0
+    return int(_max_column_nnz(w.reshape((-1,) + w.shape[-2:]),
+                               tile=tuple(tile)))
+
+
+@functools.partial(jax.jit, static_argnames="tile")
+def _max_column_nnz(flat, *, tile):
     bk, bn = tile
-    flat = w.reshape((-1,) + w.shape[-2:])
     wp = jax.vmap(lambda m: _pad_to_tiles(m, tile))(flat)
     kp, np_ = wp.shape[-2:]
     t = wp.reshape(flat.shape[0], kp // bk, bk, np_ // bn, bn)
-    return int(jnp.max(jnp.sum(t != 0, axis=2)))
+    return jnp.max(jnp.sum(t != 0, axis=2))
 
 
 def observed_block_cap(w, tile: tuple[int, int], br: int) -> int:
@@ -449,79 +455,74 @@ def pack_tiled_csc(
     value buffer after packing (:func:`quantize_packed`).
 
     Leading dims (layer stacks / experts) are packed with a *shared* cap so
-    the result slices homogeneously under ``lax.scan``.
+    the result slices homogeneously under ``lax.scan``.  One compiled
+    program packs every matrix of the stack in turn.
     """
     w = jnp.asarray(w)
-    if w.ndim > 2:
-        lead = w.shape[:-2]
-        flat = w.reshape((-1,) + w.shape[-2:])
-        if cap is None:
-            cap = max((observed_tiled_cap(w, tile) + 7) // 8 * 8, 8)
-        packed = [pack_tiled_csc(flat[i], tile, cap, index_dtype)
-                  for i in range(flat.shape[0])]
-        vals = jnp.stack([p.vals for p in packed]).reshape(
-            lead + packed[0].vals.shape)
-        rows = jnp.stack([p.rows for p in packed]).reshape(
-            lead + packed[0].rows.shape)
-        return quantize_packed(
-            TiledCSC(vals=vals, rows=rows, shape=tuple(w.shape[-2:]),
-                     tile=tile),
-            qmode, ncodes)
-    if w.ndim != 2:
+    if w.ndim < 2:
         raise ValueError(f"expected >=2-D matrix, got {w.shape}")
     bk, bn = tile
-    shape = tuple(w.shape)
-    w = _pad_to_tiles(w, tile)
-    kp, np_ = w.shape
-    kt_n, nt_n = kp // bk, np_ // bn
-    # (Kt, Nt, bk, bn)
-    tiles = w.reshape(kt_n, bk, nt_n, bn).transpose(0, 2, 1, 3)
-
-    nz = tiles != 0
+    lossless = cap is None
     if cap is None:
-        cap = int(jnp.max(jnp.sum(nz, axis=2))) if w.size else 0
-        cap = max(cap, 1)
-        cap = (cap + 7) // 8 * 8  # sublane-align slot dim for the TPU kernel
-    # Order rows of each tile column: non-zeros first (stable ⇒ ascending row),
-    # then pick the top `cap` slots.  For the lossy path order by |value|.
-    exact = cap >= bk
-    key_nz = (~nz).astype(jnp.int32)
-    order = jnp.argsort(key_nz, axis=2, stable=True)  # (Kt, Nt, bk, bn)
-    gathered = jnp.take_along_axis(tiles, order, axis=2)
-    gathered_nz = jnp.take_along_axis(nz, order, axis=2)
-    if not exact:
-        # keep largest-|value| entries when truncating
-        mag_order = jnp.argsort(
-            jnp.where(gathered_nz, -jnp.abs(gathered.astype(jnp.float32)), jnp.inf),
-            axis=2,
-            stable=True,
-        )
-        keep = mag_order[:, :, :cap, :]
-        vals = jnp.take_along_axis(gathered, keep, axis=2)
-        # restore ascending-row order within the kept set
-        row_ids = jnp.take_along_axis(order, keep, axis=2)
-        asc = jnp.argsort(row_ids, axis=2, stable=True)
-        rows = jnp.take_along_axis(row_ids, asc, axis=2)
-        vals = jnp.take_along_axis(vals, asc, axis=2)
-        valid = jnp.take_along_axis(jnp.take_along_axis(gathered_nz, keep, axis=2), asc, axis=2)
-    else:
-        cap_eff = min(cap, bk)
-        vals = gathered[:, :, :cap_eff, :]
-        rows = order[:, :, :cap_eff, :]
-        valid = gathered_nz[:, :, :cap_eff, :]
-        if cap > bk:  # degenerate: more slots than rows
-            pad = cap - bk
-            vals = jnp.pad(vals, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            valid = jnp.pad(valid, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    vals = jnp.where(valid, vals, 0).astype(w.dtype)
-    rows = jnp.where(valid, rows, -1)
+        cap = max((observed_tiled_cap(w, tile) + 7) // 8 * 8, 8)
     if index_dtype is None:
         index_dtype = jnp.int8 if bk <= 128 else jnp.int32
-    rows = rows.astype(index_dtype)
+    lead, shape = w.shape[:-2], tuple(w.shape[-2:])
+    vals, rows = _pack_tiles(w.reshape((-1,) + shape), tile=(bk, bn),
+                             cap=int(cap), lossless=lossless,
+                             index_dtype=jnp.dtype(index_dtype))
     return quantize_packed(
-        TiledCSC(vals=vals, rows=rows, shape=shape, tile=(bk, bn)),
+        TiledCSC(vals=vals.reshape(lead + vals.shape[1:]),
+                 rows=rows.reshape(lead + rows.shape[1:]), shape=shape,
+                 tile=(bk, bn)),
         qmode, ncodes)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("tile", "cap", "lossless", "index_dtype"))
+def _pack_tiles(flat, *, tile, cap, lossless, index_dtype):
+    """``(vals, rows)`` of a stack of matrices ``(B, K, N)``, each
+    ``(Kt, Nt, cap, bn)``: per tile column, the rows it keeps, a slot that
+    holds no non-zero given row -1 and value 0.
+
+    With ``cap >= bk`` a column keeps every row, the non-zeros first, each
+    group in row order.  With a ``lossless`` cap it keeps every non-zero
+    and the first ``cap - count`` zero rows, in row order.  With any
+    other cap it keeps the ``cap`` largest magnitudes, ties to the lower
+    row, in row order.  A stable sort of the column's rows, carrying
+    their values, picks them: no gather."""
+    bk, bn = tile
+
+    def one(m):
+        m = _pad_to_tiles(m, tile)
+        kp, np_ = m.shape
+        # (Kt, Nt, bn, bk): each tile column's rows on the minor axis
+        cols = m.reshape(kp // bk, bk, np_ // bn, bn).transpose(0, 2, 3, 1)
+        nz = cols != 0
+        row = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 3)
+        if cap >= bk:
+            key = (~nz).astype(jnp.int32)
+        elif lossless:
+            zeros_before = jnp.cumsum(~nz, axis=3, dtype=jnp.int32) - ~nz
+            free = cap - jnp.sum(nz, axis=3, keepdims=True, dtype=jnp.int32)
+            key = (~(nz | (zeros_before < free))).astype(jnp.int32)
+        else:
+            key = jnp.where(nz, -jnp.abs(cols.astype(jnp.float32)), jnp.inf)
+        _, rows, vals = jax.lax.sort((key, row, cols), dimension=3,
+                                     is_stable=True, num_keys=1)
+        rows, vals = rows[..., :cap], vals[..., :cap]
+        if cap < bk and not lossless:
+            rows, vals = jax.lax.sort((rows, vals), dimension=3, num_keys=1)
+        valid = vals != 0
+        vals = jnp.where(valid, vals, 0).astype(m.dtype)
+        rows = jnp.where(valid, rows, -1).astype(index_dtype)
+        if cap > bk:  # degenerate: more slots than rows
+            pad = ((0, 0), (0, 0), (0, 0), (0, cap - bk))
+            vals = jnp.pad(vals, pad)
+            rows = jnp.pad(rows, pad, constant_values=-1)
+        return vals.transpose(0, 1, 3, 2), rows.transpose(0, 1, 3, 2)
+
+    return jax.lax.map(one, flat)
 
 
 # ---------------------------------------------------------------------------

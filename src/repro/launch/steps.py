@@ -130,14 +130,16 @@ def make_paged_decode_step(model: LM, mesh=None, plan=None):
     every engine slot decodes at its own ``pos`` against its own pages.
     ``valid_len`` (optional, (B,)) is the per-row write cutoff the engine
     uses to batch decoding rows with prefilling/idle ones — rows at or
-    beyond their cutoff write to the trash page."""
+    beyond their cutoff write to the trash page.  Returns (next tokens,
+    logits, pool), then whatever counters the model's step returns (a
+    model with held experts: the rows routed to them)."""
     def paged_decode_step(params: Params, pool: Params, block_tables,
                           tokens, pos, valid_len=None):
         with mesh_context(mesh), use_plan(plan):
-            logits, pool = model.paged_decode_step(
+            logits, pool, *counters = model.paged_decode_step(
                 params, pool, block_tables, tokens, pos,
                 valid_len=valid_len)
         next_tokens = jnp.argmax(logits, axis=-1)
-        return next_tokens, logits, pool
+        return (next_tokens, logits, pool, *counters)
 
     return paged_decode_step

@@ -102,13 +102,15 @@ def _online_block(carry, scores, v_blk, mask):
 def chunked_attention(
     q: jax.Array,             # (B, S, H, hd)
     k: jax.Array,             # (B, S, KV, hd)
-    v: jax.Array,             # (B, S, KV, hd)
+    v: jax.Array,             # (B, S, KV, hv)
     spec: AttnSpec,
     window: int | None = None,
 ) -> jax.Array:
-    """Causal (optionally sliding-window) attention, O(S) memory."""
+    """Causal (optionally sliding-window) attention, O(S) memory.
+    Returns (B, S, H, hv)."""
     b, s, h, hd = q.shape
     kvh = k.shape[2]
+    hv = v.shape[-1]          # value width (latent attention: < hd)
     g = h // kvh
     cq = min(spec.chunk_q, s)
     ck = min(spec.chunk_k, s)
@@ -134,7 +136,7 @@ def chunked_attention(
             else:
                 raw = start = c * ck
             k_blk = jax.lax.dynamic_slice(k, (0, start, 0, 0), (b, ck, kvh, hd))
-            v_blk = jax.lax.dynamic_slice(v, (0, start, 0, 0), (b, ck, kvh, hd))
+            v_blk = jax.lax.dynamic_slice(v, (0, start, 0, 0), (b, ck, kvh, hv))
             k_pos = start + jnp.arange(ck)
             mask = k_pos[None, :] <= q_pos[:, None]
             if window is not None:
@@ -150,16 +152,16 @@ def chunked_attention(
         init = (
             jnp.full((b, kvh, g, cq), NEG_INF, jnp.float32),
             jnp.zeros((b, kvh, g, cq), jnp.float32),
-            jnp.zeros((b, kvh, g, cq, hd), jnp.float32),
+            jnp.zeros((b, kvh, g, cq, hv), jnp.float32),
         )
         n_steps = n_rel if window is not None else nk
         (m, l, acc), _ = jax.lax.scan(kv_step, init, jnp.arange(n_steps))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]        # (B,KV,G,Cq,hd)
-        return out.transpose(0, 3, 1, 2, 4).reshape(b, cq, h, hd)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]        # (B,KV,G,Cq,hv)
+        return out.transpose(0, 3, 1, 2, 4).reshape(b, cq, h, hv)
 
     out = jax.lax.map(q_chunk_body, jnp.arange(nq))
-    # (nq, B, Cq, H, hd) → (B, S, H, hd)
-    out = out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
+    # (nq, B, Cq, H, hv) → (B, S, H, hv)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hv)
     return out.astype(q.dtype)
 
 
@@ -290,20 +292,34 @@ def paged_kv(pool: Params, layer, page: jax.Array, off: jax.Array,
     (N, KV, hd).  Returns (pool, k_cache, v_cache), the caches
     (B, max_pages·page, KV, hd).
     """
-    n_pages = pool["k"].shape[-4]
+    pool, cache = paged_update(pool, layer, page, off,
+                               {"k": k_new, "v": v_new}, block_tables)
+    return pool, cache["k"], cache["v"]
+
+
+def paged_update(pool: Params, layer, page: jax.Array, off: jax.Array,
+                 new: Params, block_tables: jax.Array):
+    """:func:`paged_kv` for any sides of a pool, each (..., n_pages, page,
+    *row): write each side's new rows ``new[name]`` (N, *row) at
+    ``page``/``off``, then gather the side's rows back position-ordered,
+    (B, max_pages·page, *row).  Returns (pool, caches), both keyed as
+    ``new``."""
+    first = next(iter(new))
+    n_pages = pool[first].shape[-new[first].ndim - 1]
     page = layer * n_pages + page
     rows = layer * n_pages + block_tables
     b = block_tables.shape[0]
 
     def update(a, new):
-        flat = a.reshape((-1,) + a.shape[-3:])
+        flat = a.reshape((-1,) + a.shape[-new.ndim:])
         flat = flat.at[page, off].set(new)
-        cache = flat[rows].reshape(b, -1, *a.shape[-2:])
+        cache = flat[rows].reshape(b, -1, *a.shape[1 - new.ndim:])
         return flat.reshape(a.shape), cache
 
-    k_pool, k_cache = update(pool["k"], k_new)
-    v_pool, v_cache = update(pool["v"], v_new)
-    return {"k": k_pool, "v": v_pool}, k_cache, v_cache
+    out, caches = dict(pool), {}
+    for name, rows_new in new.items():
+        out[name], caches[name] = update(pool[name], rows_new)
+    return out, caches
 
 
 def paged_prefill_attention(
@@ -482,4 +498,190 @@ def paged_decode_attention(
                                       v_new[:, 0], block_tables)
     out = _attend_cached(q, k_cache, v_cache, pos, spec, window)
     out = out.astype(x.dtype)
+    return sod.apply(out, params["wo"]), pool
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Latent attention without a query compression (``q_lora_rank``
+    null): queries are ``qk_nope`` un-roped plus ``qk_rope`` roped dims a
+    head; keys and values are up-projected from a ``kv_lora_rank`` latent
+    shared by all heads, plus one roped key of ``qk_rope`` dims shared by
+    all heads."""
+
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    yarn: Any = None                # layers.Yarn, or None
+    norm_eps: float = 1e-6
+    chunk: int = 512
+
+    @property
+    def row_dim(self) -> int:
+        """Width of one cached row: the normed latent, then the roped key."""
+        return self.kv_lora_rank + self.qk_rope
+
+    @property
+    def cache_dim(self) -> int:
+        """A cached row padded with zeros to whole 128-lane tiles (576 →
+        640): with a row that tiles the chip's lanes the pool keeps its
+        position-major layout, where an unpadded one is laid out
+        position-minor by the compiler and copied whole to be written."""
+        return -(-self.row_dim // 128) * 128
+
+    @property
+    def scale(self) -> float:
+        return layers.yarn_softmax_scale(self.qk_nope + self.qk_rope,
+                                         self.yarn)
+
+    def full_spec(self) -> AttnSpec:
+        """The prefill's attention: every head its own up-projected keys
+        (``qk_nope + qk_rope`` wide) and values (``v_dim``)."""
+        return AttnSpec(n_heads=self.n_heads, n_kv_heads=self.n_heads,
+                        head_dim=self.qk_nope + self.qk_rope,
+                        scale=self.scale, chunk_q=self.chunk,
+                        chunk_k=self.chunk)
+
+
+def init_mla(key, d_model: int, spec: MLASpec,
+             dtype=jnp.bfloat16) -> Params:
+    """``wq`` (the query), ``wkv_a`` (down to the latent and the shared
+    roped key), the latent's RMSNorm gain ``kv_norm``, ``wkv_b`` (the
+    latent up to every head's un-roped key and value) and ``wo``."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    h = spec.n_heads
+    return {
+        "wq": layers.dense_init(kq, d_model,
+                                h * (spec.qk_nope + spec.qk_rope), dtype),
+        "wkv_a": layers.dense_init(ka, d_model, spec.row_dim, dtype),
+        "kv_norm": layers.init_rms_norm(spec.kv_lora_rank),
+        "wkv_b": layers.dense_init(kb, spec.kv_lora_rank,
+                                   h * (spec.qk_nope + spec.v_dim), dtype),
+        "wo": layers.dense_init(ko, h * spec.v_dim, d_model, dtype),
+    }
+
+
+def _mla_query(params: Params, x: jax.Array, spec: MLASpec,
+               positions: jax.Array):
+    """(q_nope (B,S,H,qk_nope), q_pe (B,S,H,qk_rope) roped)."""
+    b, s, _ = x.shape
+    q = sod.apply(x, params["wq"]).reshape(b, s, spec.n_heads, -1)
+    q_pe = layers.apply_rope(q[..., spec.qk_nope:], positions,
+                             spec.rope_theta, spec.yarn)
+    return q[..., :spec.qk_nope], q_pe
+
+
+def mla_rows(params: Params, x: jax.Array, spec: MLASpec,
+             positions: jax.Array) -> jax.Array:
+    """Each position's cached row (B, S, cache_dim): the normed latent
+    ``c_KV`` and the roped shared key ``k_R`` (the paper's eqs. 9 and
+    14), all that latent attention keeps of a position, then zeros."""
+    kv = sod.apply(x, params["wkv_a"])
+    r = spec.kv_lora_rank
+    c = layers.rms_norm(kv[..., :r], params["kv_norm"], spec.norm_eps)
+    k_pe = layers.apply_rope(kv[..., None, r:], positions, spec.rope_theta,
+                             spec.yarn)[..., 0, :]
+    pad = jnp.zeros(c.shape[:-1] + (spec.cache_dim - spec.row_dim,), c.dtype)
+    return jnp.concatenate([c, k_pe, pad], axis=-1)
+
+
+def mla_full(params: Params, x: jax.Array, spec: MLASpec,
+             positions: jax.Array):
+    """Full-sequence latent attention as the paper writes it: keys and
+    values up-projected from the latent for every head, causal attention
+    over them.  Returns (output (B,S,D), cached rows (B,S,cache_dim))."""
+    b, s, _ = x.shape
+    h, nope, r = spec.n_heads, spec.qk_nope, spec.kv_lora_rank
+    q_nope, q_pe = _mla_query(params, x, spec, positions)
+    rows = mla_rows(params, x, spec, positions)
+    kv = sod.apply(rows[..., :r], params["wkv_b"]).reshape(b, s, h, -1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(rows[:, :, None, r:spec.row_dim],
+                                          (b, s, h, spec.qk_rope))], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    out = chunked_attention(q, k, kv[..., nope:], spec.full_spec())
+    return sod.apply(out.reshape(b, s, -1), params["wo"]), rows
+
+
+def _mla_absorbed(params: Params, q_nope: jax.Array, q_pe: jax.Array,
+                  cache: jax.Array, mask: jax.Array, spec: MLASpec):
+    """Attention over cached rows with ``W_UK`` absorbed into the query and
+    ``W_UV`` applied after: one key head of ``row_dim`` (latent, then roped
+    key; the zero padding adds nothing) and one value head of the latent,
+    shared by every query head.  q_nope (B,C,H,qk_nope), q_pe
+    (B,C,H,qk_rope), cache (B,L,cache_dim), mask (B,C,L).  Returns (B, C,
+    H·v_dim)."""
+    h, nope, r = spec.n_heads, spec.qk_nope, spec.kv_lora_rank
+    w = params["wkv_b"].reshape(r, h, nope + spec.v_dim)
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bchd,rhd->bchr", q_nope, w[..., :nope],
+                           preferred_element_type=jnp.float32)
+        pad = jnp.zeros(q_pe.shape[:-1] + (spec.cache_dim - spec.row_dim,),
+                        cache.dtype)
+        q_abs = jnp.concatenate([q_lat.astype(cache.dtype),
+                                 q_pe.astype(cache.dtype), pad], -1)
+        scores = jnp.einsum("bchl,bsl->bhcs", q_abs, cache,
+                            preferred_element_type=jnp.float32) * spec.scale
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        o_lat = jnp.einsum("bhcs,bsr->bhcr", p.astype(cache.dtype),
+                           cache[..., :r], preferred_element_type=jnp.float32)
+        out = jnp.einsum("bhcr,rhv->bchv", o_lat.astype(cache.dtype),
+                         w[..., nope:], preferred_element_type=jnp.float32)
+    return out.reshape(*out.shape[:2], -1).astype(q_nope.dtype)
+
+
+def mla_decode(params: Params, x: jax.Array, cache: jax.Array,
+               pos: jax.Array, spec: MLASpec):
+    """One decode step against a dense per-slot row cache (B, L, cache_dim):
+    write the new row at ``pos`` (scalar or (B,)), attend absorbed.
+    Returns (output (B,1,D), cache)."""
+    b = x.shape[0]
+    pos = jnp.asarray(pos)
+    positions = _decode_positions(pos, b)
+    q_nope, q_pe = _mla_query(params, x, spec, positions)
+    new = mla_rows(params, x, spec, positions)                # (B,1,R)
+    if pos.ndim == 0:
+        cache = jax.lax.dynamic_update_slice(cache, new, (0, pos, 0))
+    else:
+        cache = jax.vmap(
+            lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0))
+        )(cache, new, pos)
+    pos_b = jnp.broadcast_to(pos, (b,))
+    mask = jnp.arange(cache.shape[1])[None, :] <= pos_b[:, None]
+    out = _mla_absorbed(params, q_nope, q_pe, cache, mask[:, None], spec)
+    return sod.apply(out, params["wo"]), cache
+
+
+def mla_paged_decode(params: Params, x: jax.Array, pool: Params,
+                     block_tables: jax.Array, pos: jax.Array, spec: MLASpec,
+                     valid_len: jax.Array | None = None, layer=0):
+    """One decode step against the latent page pool ``{"latent": (...,
+    n_pages, page, cache_dim)}``: the new row is written through
+    :func:`paged_update` (rows at or beyond ``valid_len`` go to the trash
+    page), the row's pages are gathered back and attended absorbed.
+    ``pool`` and ``layer`` are as in :func:`paged_kv`; the whole pool comes
+    back."""
+    b = x.shape[0]
+    pos = jnp.asarray(pos, jnp.int32)
+    positions = _decode_positions(pos, b)
+    q_nope, q_pe = _mla_query(params, x, spec, positions)
+    new = mla_rows(params, x, spec, positions)[:, 0]          # (B,R)
+    page_size = pool["latent"].shape[-2]
+    page = jnp.take_along_axis(
+        block_tables, (pos // page_size)[:, None], axis=1)[:, 0]
+    if valid_len is not None:
+        valid_len = jnp.asarray(valid_len, jnp.int32).reshape(b)
+        page = jnp.where(pos < valid_len, page, 0)      # overflow → trash
+    pool, cache = paged_update(pool, layer, page, pos % page_size,
+                               {"latent": new}, block_tables)
+    rows = cache["latent"]                                   # (B,L,P)
+    mask = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+    out = _mla_absorbed(params, q_nope, q_pe, rows, mask[:, None], spec)
     return sod.apply(out, params["wo"]), pool

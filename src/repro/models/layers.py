@@ -7,6 +7,7 @@ runs the Sparse-on-Dense datapath; dense leaves bypass decompression.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -53,13 +54,71 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures it:
+    context stretched ``factor`` times past ``original_max_position``,
+    frequencies blended between interpolated and original by a linear ramp
+    over the dims whose rotations at ``original_max_position`` lie between
+    ``beta_slow`` and ``beta_fast``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 * mscale * ln(factor) + 1`` (1 for ``factor <= 1``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, yarn: Yarn) -> jax.Array:
+    """Per-pair rotation frequencies under YaRN scaling, (head_dim/2,)."""
+    def corr_dim(rotations: float) -> float:
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_frequencies(head_dim, theta)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_softmax_scale(head_dim: int, yarn: Yarn | None) -> float:
+    """Attention logit scale: ``head_dim**-0.5``, times
+    ``yarn_mscale(factor, mscale_all_dim)**2`` under YaRN."""
+    scale = head_dim ** -0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Yarn | None = None) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  Rotate-half
+    layout; ``yarn`` scales the frequencies and cos/sin by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                       # (hd/2,)
+    if yarn is None:
+        freqs = rope_frequencies(hd, theta)                   # (hd/2,)
+    else:
+        freqs = yarn_frequencies(hd, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                              # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -27,6 +27,8 @@ class LM:
             return transformer.init_hybrid(key, cfg)
         if cfg.family == "ssm":
             return transformer.init_xlstm_lm(key, cfg)
+        if cfg.latent_attention:
+            return transformer.init_latent(key, cfg)
         return transformer.init_transformer(key, cfg)
 
     # -- full-sequence forward ------------------------------------------------
@@ -36,6 +38,8 @@ class LM:
             return transformer.hybrid_forward(params, batch, cfg, want_cache)
         if cfg.family == "ssm":
             return transformer.xlstm_forward(params, batch, cfg, want_cache)
+        if cfg.latent_attention:
+            return transformer.latent_forward(params, batch, cfg, want_cache)
         return transformer.transformer_forward(params, batch, cfg, want_cache)
 
     def loss(self, params: Params, batch: Params):
@@ -57,6 +61,8 @@ class LM:
             return transformer.hybrid_init_cache(cfg, batch, max_len)
         if cfg.family == "ssm":
             return transformer.xlstm_init_cache(cfg, batch, max_len)
+        if cfg.latent_attention:
+            return transformer.latent_init_cache(cfg, batch, max_len)
         return transformer.transformer_init_cache(cfg, batch, max_len)
 
     def prefill(self, params: Params, batch: Params):
@@ -76,6 +82,8 @@ class LM:
             return transformer.hybrid_decode(params, cache, tokens, pos, cfg)
         if cfg.family == "ssm":
             return transformer.xlstm_decode(params, cache, tokens, pos, cfg)
+        if cfg.latent_attention:
+            return transformer.latent_decode(params, cache, tokens, pos, cfg)
         return transformer.transformer_decode(params, cache, tokens, pos, cfg)
 
     # -- cache geometry (serving engine / serve driver) ------------------------
@@ -87,6 +95,8 @@ class LM:
             return transformer.hybrid_cache_spec(cfg)
         if cfg.family == "ssm":
             return transformer.xlstm_cache_spec(cfg)
+        if cfg.latent_attention:
+            return transformer.latent_cache_spec(cfg)
         return transformer.transformer_cache_spec(cfg)
 
     def grow_cache(self, cache: Params, new_len: int) -> Params:
@@ -102,6 +112,9 @@ class LM:
             raise ValueError(
                 f"family {self.cfg.family!r} keeps O(1) recurrent state per "
                 "slot; only attention-family KV caches are paged")
+        if self.cfg.latent_attention:
+            return transformer.latent_init_paged_pool(
+                self.cfg, n_pages, page_size)
         return transformer.transformer_init_paged_pool(
             self.cfg, n_pages, page_size)
 
@@ -112,10 +125,16 @@ class LM:
         """Ragged decode step over the paged KV pool: tokens (B, 1), pos
         (B,), block_tables (B, max_pages).  ``valid_len`` (optional, (B,))
         is a per-row write cutoff — rows at or beyond it redirect their KV
-        write to the trash page.  Returns (logits, pool)."""
+        write to the trash page.  Returns (logits, pool), and for latent
+        attention's held-expert model the rows routed to a held expert,
+        summed over experts and MoE layers, after those."""
         if self.cfg.family in ("hybrid", "ssm"):
             raise ValueError(
                 f"family {self.cfg.family!r} has no paged decode path")
+        if self.cfg.latent_attention:
+            return transformer.latent_decode_paged(
+                params, pool, block_tables, tokens, pos, self.cfg,
+                valid_len=valid_len)
         return transformer.transformer_decode_paged(
             params, pool, block_tables, tokens, pos, self.cfg,
             valid_len=valid_len)
@@ -130,6 +149,9 @@ class LM:
             raise ValueError(
                 f"family {self.cfg.family!r} has no paged prefill path — "
                 "recurrent prompts replay through the decode step")
+        if self.cfg.latent_attention:
+            raise ValueError("latent attention prefills a whole prompt at "
+                             "admission; it has no chunked prefill path")
         return transformer.transformer_prefill_chunk(
             params, pool, block_tables, tokens, start, valid_len, self.cfg)
 
@@ -144,6 +166,9 @@ class LM:
             raise ValueError(
                 f"family {self.cfg.family!r} has no paged verify path — "
                 "speculative decoding needs the paged-KV cache")
+        if self.cfg.latent_attention:
+            raise ValueError("latent attention has no speculative verify "
+                             "path")
         return transformer.transformer_verify_chunk(
             params, pool, block_tables, tokens, start, valid_len, self.cfg)
 

@@ -302,3 +302,85 @@ def moe_mlp(params: Params, x: jax.Array, spec: MoESpec, plans=None):
     aux = spec.router_aux_weight * jnp.sum(frac * me) * e
 
     return combined.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# held experts: one chip's share of an expert-parallel layer, dropless
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class HeldSpec:
+    """An expert layer told which of its experts it holds.  The router
+    scores all ``n_experts`` and picks ``top_k``, each weighted by its
+    softmax score, unnormalised (DeepSeek-V2's routing); only the ``held``
+    experts' part of the result is computed, plus the shared experts
+    (always on, ungated), so the shares of every chip add up to the whole
+    layer with the shared experts counted once."""
+
+    n_experts: int                  # the router's width: the whole layer
+    held: tuple[int, ...]           # expert ids this chip holds
+    top_k: int
+    d_model: int
+    d_ff: int                       # per-expert hidden
+    d_shared_ff: int = 0            # the shared experts' hidden (0: none)
+    act: str = "silu"
+
+
+def init_held_moe(key, spec: HeldSpec, dtype=jnp.bfloat16) -> Params:
+    ks = jax.random.split(key, 5)
+    e, d, f = len(spec.held), spec.d_model, spec.d_ff
+
+    def expert_init(k, d_in, d_out):
+        return jax.vmap(lambda kk: layers.dense_init(kk, d_in, d_out, dtype))(
+            jax.random.split(k, e))
+
+    params: Params = {
+        "router": layers.dense_init(ks[0], d, spec.n_experts, jnp.float32),
+        "w_gate": expert_init(ks[1], d, f),
+        "w_up": expert_init(ks[2], d, f),
+        "w_down": expert_init(ks[3], f, d),
+    }
+    if spec.d_shared_ff:
+        params["shared"] = layers.init_mlp(ks[4], d, spec.d_shared_ff, dtype)
+    return params
+
+
+def held_route(router: jax.Array, xt: jax.Array, spec: HeldSpec):
+    """Each token's weight on each held expert, (T, E_held) float32 (0
+    where the token did not pick it), and whether it picked it.  Scores
+    are a float32 softmax over every expert at full precision."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        vals, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                  spec.top_k)
+        hit = ids[:, :, None] == jnp.asarray(spec.held, jnp.int32)
+        weights = jnp.sum(jnp.where(hit, vals[:, :, None], 0.0), axis=1)
+    return weights, jnp.any(hit, axis=1)
+
+
+def held_moe(params: Params, x: jax.Array, spec: HeldSpec):
+    """x (B, S, D) → (the held experts' part of the layer plus the shared
+    experts', (B, S, D); the rows routed to a held expert, summed over
+    the held experts).
+
+    Dropless and batch-independent: each held expert multiplies every row
+    of the step (one packed matmul per expert matrix, so every one runs
+    the Sparse-on-Dense kernel) and a row keeps the product at its routing
+    weight, which is exactly 0 where it did not pick that expert.  No
+    capacity, so no row is ever dropped and a row's result does not depend
+    on the other rows of its batch."""
+    b, s, d = x.shape
+    xt = x.reshape(-1, d)
+    weights, routed = held_route(params["router"], xt, spec)
+    out = jnp.zeros(xt.shape, jnp.float32)
+    with jax.named_scope("moe.held_experts"):
+        for e in range(len(spec.held)):
+            w = {k: jax.tree_util.tree_map(lambda a: a[e], params[k])
+                 for k in ("w_gate", "w_up", "w_down")}
+            y = layers.mlp(w, xt, spec.act)
+            out = out + weights[:, e:e + 1] * y.astype(jnp.float32)
+    if "shared" in params:
+        out = out + layers.mlp(params["shared"], xt, spec.act).astype(
+            jnp.float32)
+    rows = jnp.sum(routed, dtype=jnp.int32)
+    return out.astype(x.dtype).reshape(b, s, d), rows

@@ -309,6 +309,10 @@ def project_logits(params: Params, x: jax.Array, cfg: ModelConfig):
 # TransformerLM (dense / moe / vlm / audio)
 # ---------------------------------------------------------------------------
 def init_transformer(key, cfg: ModelConfig) -> Params:
+    if cfg.experts_held or cfg.first_dense_layers or cfg.d_ff_dense:
+        raise ValueError("experts_held, first_dense_layers and d_ff_dense "
+                         "are read only with latent attention "
+                         "(kv_lora_rank > 0)")
     p_period = cfg.pattern_period
     n_groups = cfg.n_layers // p_period
     ks = jax.random.split(key, 2)
@@ -516,6 +520,194 @@ def transformer_prefill_chunk(params: Params, pool: Params,
 
     x, pool = _paged_layers(params, x, pool, cfg, block)
     return project_logits(params, x, cfg), pool
+
+
+# ---------------------------------------------------------------------------
+# LatentLM (DeepSeek-V2): latent attention, leading dense layers, then a
+# scan over layers whose MLP is a held-expert MoE
+# ---------------------------------------------------------------------------
+def mla_spec(cfg: ModelConfig) -> attn.MLASpec:
+    return attn.MLASpec(
+        n_heads=cfg.n_heads, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope=cfg.qk_nope_head_dim, qk_rope=cfg.qk_rope_head_dim,
+        v_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta, yarn=cfg.yarn,
+        norm_eps=cfg.norm_eps, chunk=cfg.attn_chunk)
+
+
+def held_spec(cfg: ModelConfig) -> moe.HeldSpec:
+    return moe.HeldSpec(
+        n_experts=cfg.n_experts, held=tuple(cfg.experts_held),
+        top_k=cfg.top_k, d_model=cfg.d_model, d_ff=cfg.d_ff,
+        d_shared_ff=cfg.d_shared_ff, act=cfg.act)
+
+
+def init_latent_block(key, cfg: ModelConfig, dense: bool) -> Params:
+    """One layer: latent attention, then a dense MLP of ``d_ff_dense``
+    (``dense``) or the held-expert MoE."""
+    ks = jax.random.split(key, 2)
+    dt = _dtype(cfg)
+    p: Params = {
+        "norm1": layers.init_rms_norm(cfg.d_model),
+        "norm2": layers.init_rms_norm(cfg.d_model),
+        "attn": attn.init_mla(ks[0], cfg.d_model, mla_spec(cfg), dt),
+    }
+    if dense:
+        p["mlp"] = layers.init_mlp(ks[1], cfg.d_model, cfg.d_ff_dense, dt)
+    else:
+        p["moe"] = moe.init_held_moe(ks[1], held_spec(cfg), dt)
+    return p
+
+
+def init_latent(key, cfg: ModelConfig) -> Params:
+    """``dense_blocks`` stacks the ``first_dense_layers`` leading layers,
+    ``blocks`` the rest (scanned)."""
+    if cfg.n_experts and not cfg.experts_held:
+        raise ValueError("a latent MoE needs experts_held: the ids of the "
+                         "experts this chip holds")
+    ks = jax.random.split(key, 3)
+    n_dense = cfg.first_dense_layers
+    params = init_embed_head(ks[0], cfg)
+    if n_dense:
+        params["dense_blocks"] = jax.vmap(
+            lambda k: init_latent_block(k, cfg, True))(
+                jax.random.split(ks[1], n_dense))
+    params["blocks"] = jax.vmap(lambda k: init_latent_block(k, cfg, False))(
+        jax.random.split(ks[2], cfg.n_layers - n_dense))
+    return params
+
+
+def _latent_ffn(bp: Params, h: jax.Array, cfg: ModelConfig):
+    """(the block's MLP or MoE output, rows routed to its held experts)."""
+    if "moe" in bp:
+        return moe.held_moe(bp["moe"], h, held_spec(cfg))
+    return layers.mlp(bp["mlp"], h, cfg.act), jnp.zeros((), jnp.int32)
+
+
+def _latent_layers(params: Params, x: jax.Array, carry, cfg: ModelConfig,
+                   attend, remat: bool = False):
+    """Run the leading dense layers, then scan the rest (rematerialised
+    when ``remat``).  ``attend(ap, h, carry, layer)`` is one layer's
+    attention over its normed input; it returns (output, carry, y).
+    Returns (x, carry, rows routed to held experts summed over layers, the
+    ys stacked over layers or None)."""
+
+    def block(bp, x, carry, layer):
+        h = layers.rms_norm(x, bp["norm1"], cfg.norm_eps)
+        o, carry, y = attend(bp["attn"], h, carry, layer)
+        x = x + o
+        h = layers.rms_norm(x, bp["norm2"], cfg.norm_eps)
+        f, rows = _latent_ffn(bp, h, cfg)
+        return x + f, carry, rows, y
+
+    n_dense = cfg.first_dense_layers
+    rows = jnp.zeros((), jnp.int32)
+    ys = []
+    for i in range(n_dense):
+        bp = jax.tree_util.tree_map(lambda t: t[i], params["dense_blocks"])
+        x, carry, r, y = block(bp, x, carry, i)
+        rows = rows + r
+        ys.append(y)
+
+    def body(c, inp):
+        x, carry, rows = c
+        bp, g = inp
+        x, carry, r, y = block(bp, x, carry, n_dense + g)
+        return (x, carry, rows + r), y
+
+    if remat:
+        body = jax.checkpoint(body, prevent_cse=False)
+    n_scan = cfg.n_layers - n_dense
+    (x, carry, rows), y_scan = _scan(
+        body, (x, carry, rows),
+        (params["blocks"], np.arange(n_scan, dtype=np.int32)), cfg)
+    if y_scan is None:
+        return x, carry, rows, None
+    if ys:
+        y_scan = jnp.concatenate([jnp.stack(ys), y_scan])
+    return x, carry, rows, y_scan
+
+
+def latent_forward(params: Params, batch: Params, cfg: ModelConfig,
+                   want_cache: bool = False):
+    """Full-sequence forward (training, prefill).  The cache is every
+    layer's rows, ``{"latent": (L, 1, B, S, cache_dim)}``."""
+    x = embed_inputs(params, batch, cfg)
+    b, s, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    spec = mla_spec(cfg)
+
+    def attend(ap, h, carry, layer):
+        o, rows = attn.mla_full(ap, h, spec, positions)
+        return o, carry, (rows if want_cache else None)
+
+    x, _, _, rows = _latent_layers(params, x, None, cfg, attend,
+                                   remat=cfg.remat and not want_cache)
+    logits = project_logits(params, x, cfg)
+    cache = None
+    if want_cache:
+        cache = {"latent": rows[:, None]}
+    return logits, 0.0, cache
+
+
+def latent_decode(params: Params, cache: Params, tokens: jax.Array,
+                  pos: jax.Array, cfg: ModelConfig):
+    """One decode step over a dense row cache (the static serve path)."""
+    x = embed_inputs(params, {"tokens": tokens}, cfg)
+    spec = mla_spec(cfg)
+    lat = cache["latent"]                       # (L, 1, B, S, cache_dim)
+
+    def attend(ap, h, carry, layer):
+        o, c = attn.mla_decode(ap, h, carry[layer], pos, spec)
+        return o, carry.at[layer].set(c), None
+
+    x, lat0, _, _ = _latent_layers(params, x, lat[:, 0], cfg, attend)
+    return project_logits(params, x, cfg), {"latent": lat0[:, None]}
+
+
+def latent_init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
+    shape = (cfg.n_layers, 1, batch, max_len, mla_spec(cfg).cache_dim)
+    return {"latent": jnp.zeros(shape, _dtype(cfg))}
+
+
+def latent_cache_spec(cfg: ModelConfig) -> Params:
+    """(L, 1, B, S, cache_dim) — batch 2, sequence 3."""
+    return {"latent": cache_mod.CacheAxes(batch=2, seq=3)}
+
+
+def latent_init_paged_pool(cfg: ModelConfig, n_pages: int,
+                           page_size: int) -> Params:
+    """Every layer's latent pages, ``{"latent": (L, 1, n_pages, page,
+    cache_dim)}``: a page holds the normed latent and the roped key of
+    each of its positions, in every layer; the block tables are shared as
+    in :func:`transformer_init_paged_pool`.  Rows fill whole lane tiles
+    and no unit axis follows the page axis, so the pool keeps one layout
+    from the parameter through the layers' carry and is written in place.
+    """
+    shape = (cfg.n_layers, 1, n_pages, page_size, mla_spec(cfg).cache_dim)
+    return {"latent": jnp.zeros(shape, _dtype(cfg))}
+
+
+def latent_decode_paged(params: Params, pool: Params,
+                        block_tables: jax.Array, tokens: jax.Array,
+                        pos: jax.Array, cfg: ModelConfig,
+                        valid_len: jax.Array | None = None):
+    """One ragged decode step over the latent page pool (absorbed
+    attention).  The pool rides in the layers' carry viewed as (L, n_pages,
+    ...), as in :func:`_paged_layers`.  Returns (logits, pool, rows routed
+    to held experts over every MoE layer)."""
+    x = embed_inputs(params, {"tokens": tokens}, cfg)
+    spec = mla_spec(cfg)
+    shape = pool["latent"].shape
+    flat = {"latent": pool["latent"].reshape((-1,) + shape[2:])}
+
+    def attend(ap, h, pool, layer):
+        o, pool = attn.mla_paged_decode(ap, h, pool, block_tables, pos, spec,
+                                        valid_len=valid_len, layer=layer)
+        return o, pool, None
+
+    x, flat, rows, _ = _latent_layers(params, x, flat, cfg, attend)
+    return (project_logits(params, x, cfg),
+            {"latent": flat["latent"].reshape(shape)}, rows)
 
 
 # ---------------------------------------------------------------------------

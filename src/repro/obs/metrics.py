@@ -139,12 +139,14 @@ class StepRecord:
     ``decode_rows`` slots committed a token from its batched decode,
     ``admitted`` requests took a slot, ``completed`` finished,
     ``queue_ready`` admissible requests were left waiting after
-    admission, and ``free_pages`` pages were free at its end.
+    admission, ``free_pages`` pages were free at its end, and, for a model
+    with held experts, ``expert_rows`` rows of its batched decode were
+    routed to a held expert, summed over held experts and MoE layers.
     """
 
     __slots__ = ("step", "t_begin", "t_end", "phases", "wait_s",
                  "decode_rows", "admitted", "completed", "queue_ready",
-                 "free_pages")
+                 "free_pages", "expert_rows")
 
     def __init__(self, step: int, t_begin: float):
         self.step = step
@@ -157,6 +159,7 @@ class StepRecord:
         self.completed = 0
         self.queue_ready = 0
         self.free_pages = 0
+        self.expert_rows = 0
 
     @property
     def host_s(self) -> float:

@@ -102,7 +102,17 @@ contribute exact zeros; shared pages hold KV bytes identical to what the
 sharer's own prefill would have written, and swapped pages are restored
 byte-for-byte.  One documented exception: MoE capacity-factor routing is
 batch-global, so under expert-capacity pressure an engine batch can drop
-different tokens than a batch-1 run.
+different tokens than a batch-1 run.  The held-expert layer
+(``experts_held``, :func:`repro.models.moe.held_moe`) has no capacity and
+drops nothing, so it keeps the guarantee.
+
+Latent attention (``kv_lora_rank > 0``, DeepSeek-V2's MLA) pages the
+normed latent and the roped key of each position — one row of
+``kv_lora_rank + qk_rope_head_dim`` a layer, not keys and values per KV
+head — prefills fused at admission (keys and values up-projected as the
+paper writes them) and decodes absorbed over the gathered rows.  Chunked
+prefill, prefix sharing (and so the prefix cache) and speculative decoding
+have no latent path and are refused at construction; preemption works.
 
 All jit-compiled shapes are fixed by (max_slots, pool size, block-table
 width, prompt buckets / the chunk size), so steady-state serving never
@@ -178,8 +188,8 @@ def _pool_copy_page(pool: Params, src, dst):
 def _pool_gather_pages(pool: Params, page_ids):
     """Swap-out: pull pages ``page_ids`` (padded with the trash page to a
     fixed width, so one compile serves every page count) out of every
-    layer's pool — (G, P, n_ids, page, KV, hd)."""
-    return {"k": pool["k"][:, :, page_ids], "v": pool["v"][:, :, page_ids]}
+    layer's pool — (G, P, n_ids, page, KV, hd) per side."""
+    return {k: a[:, :, page_ids] for k, a in pool.items()}
 
 
 def _pool_scatter_pages(pool: Params, kv: Params, page_ids):
@@ -191,7 +201,7 @@ def _pool_scatter_pages(pool: Params, kv: Params, page_ids):
 def _pool_get_page(pool: Params, page_id):
     """Cache demotion: slice one page out of every layer's pool —
     (G, P, page, KV, hd) per side."""
-    return {"k": pool["k"][:, :, page_id], "v": pool["v"][:, :, page_id]}
+    return {k: a[:, :, page_id] for k, a in pool.items()}
 
 
 def _pool_set_page(pool: Params, kv: Params, page_id):
@@ -238,6 +248,12 @@ class Engine:
             raise ValueError(
                 "the prefix cache retains trie-held prompt pages: pass "
                 "prefix_sharing=True (and prefill_chunk=...) to enable it")
+        if cfg.latent_attention and (prefill_chunk or prefix_sharing
+                                     or spec_k):
+            raise ValueError(
+                "latent attention (MLA) serves fused prefill at admission "
+                "and batched absorbed decode; chunked prefill, prefix "
+                "sharing and speculative verify have no latent path")
         self.spec_k = int(spec_k or 0)
         if self.spec_k:
             if not self.paged:
@@ -1093,15 +1109,21 @@ class Engine:
             if self.paged:
                 tables = jnp.asarray(self.block_tables)
                 valid = jnp.asarray(self._valid_lens())
+        counters = ()
         with self._phase("decode.dispatch"):
             if self.paged:
-                nxt, _, self.pool = self._decode(
+                nxt, _, self.pool, *counters = self._decode(
                     self.params, self.pool, tables, tok, pos, valid)
             else:
                 nxt, _, self.cache = self._decode(
                     self.params, self.cache, tok, pos)
         with self._phase("decode.wait"):
-            nxt = np.asarray(nxt)
+            if counters:
+                # the step's counters come back with its tokens
+                nxt, rows = jax.device_get((nxt, counters[0]))
+                self.metrics.steps.current.expert_rows = int(rows)
+            else:
+                nxt = np.asarray(nxt)
         with self._phase("commit"):
             nxt = nxt.reshape(self.max_slots, -1)[:, 0]
             for slot, seq in list(decoding.items()):
@@ -1239,12 +1261,12 @@ class Engine:
             if self.prefix_cache is not None:
                 zero = jnp.asarray(PagePool.TRASH_PAGE, jnp.int32)
                 snap = self._page_get(self.pool, zero)
-                jax.block_until_ready(snap["k"])
+                jax.block_until_ready(snap)
                 self.pool = self._page_set(self.pool, snap, zero)
             if self.preemption:
                 ids = jnp.zeros(self.max_pages, jnp.int32)
                 snap = self._gather_pages(self.pool, ids)
-                jax.block_until_ready(snap["k"])
+                jax.block_until_ready(snap)
                 self.pool = self._scatter_pages(self.pool, snap, ids)
             out = self._decode(
                 self.params, self.pool, jnp.asarray(self.block_tables),

@@ -206,6 +206,7 @@ def bench(tmp_path, monkeypatch):
     monkeypatch.syspath_prepend(str(BENCH / "tests"))
     monkeypatch.syspath_prepend(str(BENCH))
     import tiny
+    import tiny_mixes  # noqa: F401  (the mixes tiny.py predates)
 
     return tiny.make(tmp_path / "root")
 

@@ -67,6 +67,54 @@ def test_tiled_csc_lossy_cap_keeps_largest():
         assert np.abs(orig[kept]).min() >= np.abs(orig[dropped]).max() - 1e-6
 
 
+def _slots_by_hand(w, tile, cap, lossless):
+    """Each tile column's (values, rows), written out column by column:
+    with ``cap >= bk`` the non-zeros first, then the zero rows; with a
+    lossless cap every non-zero and the first zero rows that fill ``cap``,
+    in row order; otherwise the ``cap`` largest magnitudes, ties to the
+    lower row, in row order.  A slot without a non-zero holds (0, -1)."""
+    bk, bn = tile
+    kp, np_ = formats.padded_shape(w.shape, tile)
+    w = np.pad(w, ((0, kp - w.shape[0]), (0, np_ - w.shape[1])))
+    vals = np.zeros((kp // bk, np_ // bn, max(cap, bk), bn), w.dtype)
+    rows = np.full(vals.shape, -1)
+    for kt in range(kp // bk):
+        for c in range(np_):
+            col = w[kt * bk:(kt + 1) * bk, c]
+            nz = [r for r in range(bk) if col[r] != 0]
+            zero = [r for r in range(bk) if col[r] == 0]
+            if cap >= bk:
+                keep = nz + zero
+            elif lossless:
+                keep = sorted(nz + zero[:cap - len(nz)])
+            else:
+                keep = sorted(sorted(nz, key=lambda r: -abs(col[r]))[:cap]
+                              + zero[:max(cap - len(nz), 0)])
+            for s, r in enumerate(keep):
+                if col[r] != 0:
+                    vals[kt, c // bn, s, c % bn] = col[r]
+                    rows[kt, c // bn, s, c % bn] = r
+    return vals[:, :, :cap], rows[:, :, :cap]
+
+
+@pytest.mark.parametrize("cap", [None, 2, 4, 8, 16])
+def test_tiled_csc_slot_layout(cap):
+    """The packed slots, bit for bit, in each capacity regime, integer
+    values making magnitude ties common."""
+    rng = np.random.default_rng(5)
+    w = np.round(rng.standard_normal((2, 20, 13)) * 2).astype(np.float32)
+    w[0, :, 3] = 0.0
+    p = formats.pack_tiled_csc(jnp.asarray(w), tile=(8, 8), cap=cap)
+    for i in range(2):
+        obs = int(np.max(np.count_nonzero(
+            np.pad(w, ((0, 0), (0, 4), (0, 3))).reshape(2, 3, 8, 2, 8),
+            axis=2)))
+        c = max((obs + 7) // 8 * 8, 8) if cap is None else cap
+        vals, rows = _slots_by_hand(w[i], (8, 8), c, cap is None)
+        np.testing.assert_array_equal(np.asarray(p.vals[i]), vals)
+        np.testing.assert_array_equal(np.asarray(p.rows[i]), rows)
+
+
 def test_tiled_csc_grad_exact_on_mask():
     w = _rand_sparse(3, (256, 128), 0.3)
     p = formats.pack_tiled_csc(w)

@@ -151,3 +151,52 @@ def test_pool_programs_update_in_place_on_v5e(one_chip, v5e_pool_engine,
     from test_pool_in_place import assert_in_place
 
     assert_in_place(v5e_pool_engine, program, sharding=one_chip)
+
+
+@pytest.fixture(scope="module")
+def v5e_latent_engine():
+    from repro import configs
+    from repro.models.model import build_model
+    from repro.serving import Engine
+
+    # DeepSeek-V2-Lite's cached row (latent 512 + roped key 64) on a
+    # reduced model: the row, not the model's size, sets the pool's layout
+    cfg = configs.reduced(configs.get_config("deepseek-v2-lite")).with_(
+        kv_lora_rank=512, qk_rope_head_dim=64)
+    model = build_model(cfg)
+    return Engine(model, model.init(jax.random.PRNGKey(0)), max_slots=4,
+                  page_size=128, max_len=512, n_pages=9)
+
+
+@pytest.mark.parametrize("program", ["decode", "page_write"])
+def test_latent_pool_programs_update_in_place_on_v5e(one_chip,
+                                                     v5e_latent_engine,
+                                                     program):
+    """Latent attention's decode and page write, compiled for the chip,
+    alias the donated pool, and their temporaries do not grow with it:
+    rows padded to whole 128-lane tiles keep the pool position-major (a
+    576-wide row was laid out position-minor and the pool copied whole)."""
+    eng = v5e_latent_engine
+    side = eng.pool["latent"]
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled(n_pages):
+        pool = {"latent": sds(side.shape[:2] + (n_pages,) + side.shape[3:],
+                              side.dtype)}
+        if program == "page_write":
+            cache = {"latent": sds(side.shape[:2] + (1, 256) + side.shape[4:],
+                                   side.dtype)}
+            return eng._page_write.lower(pool, cache, sds((2,))).compile()
+        params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype),
+                                        eng.params)
+        b, w = eng.max_slots, eng.max_pages
+        return eng._decode.lower(params, pool, sds((b, w)), sds((b, 1)),
+                                 sds((b,)), sds((b,))).compile()
+
+    small, big = (compiled(n).memory_analysis() for n in (64, 1024))
+    page = side.size // side.shape[2] * side.dtype.itemsize
+    assert big.alias_size_in_bytes >= 1024 * page
+    grown = big.temp_size_in_bytes - small.temp_size_in_bytes
+    assert grown < 960 * page // eng.model.cfg.n_layers, grown
